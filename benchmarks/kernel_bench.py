@@ -40,7 +40,8 @@ from repro.core.pairings import default_n_stages, two_level_schedule
 from repro.kernels.ops import (pick_block_rows_for_plan, plan_runs,
                                plan_runs_for_rows)
 from repro.kernels.spm_stack import vmem_bytes
-from repro.launch.hlo_analysis import HW, sharded_stage_traffic
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.hlo_analysis import V5E, peaks, sharded_stage_traffic
 from repro.parallel.spm_shard import plan_steps
 
 KEY = jax.random.PRNGKey(0)
@@ -315,7 +316,7 @@ def sharded_model(n: int, batch: int, L: int,
     if out_width == n:
         out_width = None
     kw = dict(use_diag=True, use_bias=True,
-              in_width=in_width, out_width=out_width)
+              in_width=in_width, out_width=out_width, hw=peaks(V5E))
     sh = sharded_stage_traffic(n_local, batch, steps,
                                fold_boundaries=True, **kw)
     sh_ov = sharded_stage_traffic(n_local, batch, steps,
@@ -326,7 +327,7 @@ def sharded_model(n: int, batch: int, L: int,
     n_runs = len(plan_runs(n, strides))
     coeff_bytes = L * (n // 2) * 16 + 3 * n * 4
     rep_bytes = 2 * n_runs * act + coeff_bytes
-    rep_s = rep_bytes / HW["hbm_bw"]
+    rep_s = rep_bytes / peaks(V5E)["hbm_bw"]
     shard_s = sh["memory_s"] + sh["collective_s"]
     return {"n": n, "L": L, "n_shards": n_shards, "n_local": n_local,
             "in_width": in_width, "out_width": out_width,
@@ -346,43 +347,53 @@ def sharded_model(n: int, batch: int, L: int,
             "speedup_model": rep_s / shard_s if shard_s else None}
 
 
-def time_sharded_subprocess(n: int, batch: int, L: int,
-                            n_shards: int = SHARD_DEVICES,
-                            timeout: int = 600) -> dict:
-    """Wall-clock the distributed executor on virtual host devices.
+def time_sharded(n: int, batch: int, L: int,
+                 n_shards: int = SHARD_DEVICES, timeout: int = 600) -> dict:
+    """Wall-clock the distributed executor over ``n_shards`` devices.
 
-    The forced device count must be set before jax initializes, and this
-    process already owns a 1-device backend (conftest's rule), so the
-    measurement re-execs THIS file with ``--sharded-worker`` in a child
-    whose XLA_FLAGS request ``n_shards`` host devices.  Interpret-safe:
-    the worker keeps the XLA composition (use_kernel=False) on CPU."""
+    On an accelerator backend this process already holds the chips, and
+    a child process could not reach them: time in-process over the real
+    devices, or return a ``{"skipped": reason}`` row (the reason is
+    printed) when the backend has fewer than ``n_shards``.  On the CPU
+    backend the forced host-device count must be set before jax
+    initializes, and this process already owns a 1-device backend
+    (conftest's rule), so the measurement re-execs THIS file with
+    ``--sharded-worker`` in a child whose XLA_FLAGS request ``n_shards``
+    host devices.  A failed measurement raises."""
+    if jax.default_backend() != "cpu":
+        if jax.device_count() < n_shards:
+            reason = (f"needs {n_shards} devices, the "
+                      f"{jax.default_backend()} backend has "
+                      f"{jax.device_count()}")
+            print(f"# sharded timing skipped: {reason}")
+            return {"skipped": reason}
+        return _time_sharded_here(n, batch, L, n_shards)
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_shards}")
     cmd = [sys.executable, os.path.abspath(__file__),
            "--sharded-worker", f"{n},{batch},{L},{n_shards}"]
-    try:
-        r = subprocess.run(cmd, capture_output=True, text=True,
-                           timeout=timeout, env=env)
-        if r.returncode != 0:
-            return {"error": (r.stderr or r.stdout)[-500:]}
-        return json.loads(r.stdout.strip().splitlines()[-1])
-    except Exception as e:   # noqa: BLE001 — bench rows degrade, never fail
-        return {"error": f"{type(e).__name__}: {e}"}
+    r = subprocess.run(cmd, capture_output=True, text=True,
+                       timeout=timeout, env=env)
+    if r.returncode != 0:
+        raise RuntimeError(f"sharded timing worker failed "
+                           f"(exit {r.returncode}): "
+                           f"{(r.stderr or r.stdout)[-2000:]}")
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-def run_sharded_worker(spec: str) -> None:
-    """Child entry (forced multi-device backend): time sharded vs
-    replicated spm_apply on the same params and print one JSON line."""
+def _time_sharded_here(n: int, batch: int, L: int, n_shards: int) -> dict:
+    """Time sharded vs replicated spm_apply on the same params over this
+    process's first ``n_shards`` devices.  ``use_kernel`` stays auto: the
+    XLA composition on CPU, the fused kernel on TPU."""
+    import dataclasses
+
     import numpy as np
     from jax.sharding import Mesh
     from repro.parallel.ctx import activation_sharding
 
-    import dataclasses
-
-    n, batch, L, n_shards = map(int, spec.split(","))
     cfg = SPMConfig(n=n, n_stages=L, schedule="two_level",
-                    n_shards=n_shards, backward="custom", use_kernel=False,
+                    n_shards=n_shards, backward="custom", use_kernel=None,
                     overlap=False)
     cfg_ov = dataclasses.replace(cfg, overlap=True)
     p = init_spm(KEY, cfg)
@@ -409,7 +420,14 @@ def run_sharded_worker(spec: str) -> None:
             lambda x: jnp.sum(spm_apply(p, x, cfg_ov) ** 2)))
         out["sharded_overlap_fwd_us"] = time_step(ov_f, x) * 1e6
         out["sharded_overlap_fwdbwd_us"] = time_step(ov_g, x) * 1e6
-    print(json.dumps(out))
+    return out
+
+
+def run_sharded_worker(spec: str) -> None:
+    """Child entry (forced multi-device CPU backend): print one JSON line
+    of ``_time_sharded_here``."""
+    n, batch, L, n_shards = map(int, spec.split(","))
+    print(json.dumps(_time_sharded_here(n, batch, L, n_shards)))
 
 
 def main(argv=None) -> None:
@@ -429,6 +447,7 @@ def main(argv=None) -> None:
                     help="modeled sharded rows only (no timing subprocess)")
     ap.add_argument("--sharded-worker", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.sharded_worker:
         run_sharded_worker(args.sharded_worker)
         return
@@ -562,7 +581,7 @@ def main(argv=None) -> None:
                            or args.skip_sharded_timing):
             # same batch as the modeled row: the JSON record's modeled
             # seconds and measured microseconds describe ONE workload
-            sr["timing"] = time_sharded_subprocess(n, args.batch, L)
+            sr["timing"] = time_sharded(n, args.batch, L)
         sharded_records.append(sr)
         m, mo = sr["modeled"], sr["modeled_overlap"]
         print(f"{n},{sr['L']},{sr['n_shards']},{sr['n_cross_stages']},"
@@ -574,7 +593,7 @@ def main(argv=None) -> None:
               f"{sr['modeled_pr3']['hbm_bytes_per_chip']},"
               f"{sr['boundary_reduction']:.2f}x,"
               f"{sr['replicated_hbm_bytes']},{sr['speedup_model']:.2f}x")
-        if sr.get("timing") and "error" not in sr["timing"]:
+        if sr.get("timing") and "skipped" not in sr["timing"]:
             t = sr["timing"]
             emit(f"kernel/n{n}/sharded_fwd", t["sharded_fwd_us"],
                  f"replicated={t['replicated_fwd_us']:.0f}us "

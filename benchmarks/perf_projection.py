@@ -23,7 +23,7 @@ from repro.configs import SHAPES, get_config
 from repro.core.linear import LinearConfig
 from repro.core.pairings import default_n_stages
 from repro.kernels.ops import plan_runs
-from repro.launch.hlo_analysis import HW, roofline_terms
+from repro.launch.hlo_analysis import V5E, peaks, roofline_terms
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
@@ -116,7 +116,7 @@ def project(arch: str, shape_name: str, profile_file: str):
     projected = max(measured - unfused + fused, fused)
     terms_now = rec["roofline"]
     terms_proj = roofline_terms(rec["cost"]["flops"], projected,
-                                rec["collectives"]["total"])
+                                rec["collectives"]["total"], peaks(V5E))
     return {
         "cell": f"{arch} x {shape_name}",
         "measured_bytes": measured,

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.analysis.recompile import CompileTracker
 from repro.configs import get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import ContinuousBatchingEngine, Request
 
@@ -99,6 +100,7 @@ def main(argv=None) -> int:
                     default=list(DEFAULT_LOADS))
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if not args.smoke:
         print("note: full-scale serve bench off-TPU is slow; the gate "

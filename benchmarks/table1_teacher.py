@@ -16,6 +16,7 @@ import numpy as np
 from benchmarks.common import emit, time_step
 from repro.configs.paper import T1_BATCH, T1_CLASSES, student_cfg
 from repro.data import DeterministicLoader, TeacherConfig, make_teacher, teacher_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_mlp, mlp_loss
 from repro.optim import OptimizerConfig
 from repro.train import make_train_state, make_train_step
@@ -49,6 +50,7 @@ def main(argv=None) -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-exact widths/steps (slow on CPU)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     widths = (256, 512, 1024, 2048) if args.full else (128, 256, 512)
     steps = 1200 if args.full else 300
     batch = T1_BATCH if args.full else 128

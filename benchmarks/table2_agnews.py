@@ -17,6 +17,7 @@ from benchmarks.common import emit, time_step
 from repro.configs.paper import AGNEWS_CLASSES, AGNEWS_L, student_cfg
 from repro.data import DeterministicLoader
 from repro.data.hashed_text import HashedTextConfig, hashed_text_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_mlp, mlp_loss
 from repro.optim import OptimizerConfig
 from repro.train import make_train_state, make_train_step
@@ -47,6 +48,7 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     widths = (2048, 4096) if args.full else (512, 1024)
     steps = 800 if args.full else 200
     batch = 256 if args.full else 128

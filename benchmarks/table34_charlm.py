@@ -20,6 +20,7 @@ from benchmarks.common import emit
 from repro.configs.paper import CHARLM_B, CHARLM_D, CHARLM_L, CHARLM_LR, CHARLM_T
 from repro.core.linear import LinearConfig, init_linear, linear_apply
 from repro.data import build_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import OptimizerConfig
 from repro.train import make_train_state, make_train_step
 
@@ -95,6 +96,7 @@ def main(argv=None) -> None:
                     help=f"paper scale d={CHARLM_D} (slow on 1-core CPU)")
     ap.add_argument("--steps", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     d = CHARLM_D if args.full else 1024
     steps = args.steps or (800 if args.full else 60)
     eval_every = max(steps // 5, 1)

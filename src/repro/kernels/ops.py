@@ -48,8 +48,10 @@ to the last axis of ``x`` with:
   * **bf16 I/O** — activations may be bf16; in-VMEM compute is f32 and all
     parameter grads are returned f32 (cast back to the param dtype here).
 
-On CPU (this container) kernels run with ``interpret=True``; on TPU the
-same BlockSpecs compile natively.  ``kernels/ref.py`` is the oracle.
+Off-TPU the kernels run with ``interpret=True``; on TPU they compile
+through Mosaic (``tests/test_tpu_compile.py`` compiles the main path's
+kernels for a described v5e; ``chip_smoke.py`` runs them on the chip).
+``kernels/ref.py`` is the oracle.
 """
 
 from __future__ import annotations

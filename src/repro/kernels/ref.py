@@ -17,8 +17,10 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
-__all__ = ["spm_stack_ref", "spm_stack_grads_ref", "spm_full_ref"]
+__all__ = ["spm_stack_ref", "spm_stack_grads_ref", "spm_full_ref",
+           "spm_runs_ref"]
 
 
 def _stage(z, cf, s):
@@ -51,6 +53,38 @@ def spm_full_ref(x: jnp.ndarray, coeffs: jnp.ndarray,
     matching the diag/bias folding of the fused kernel path."""
     z = x if d_in is None else x * d_in.astype(x.dtype)
     z = spm_stack_ref(z, coeffs, strides)
+    if d_out is not None:
+        z = z * d_out.astype(z.dtype)
+    if bias is not None:
+        z = z + bias.astype(z.dtype)
+    return z
+
+
+def spm_runs_ref(x: jnp.ndarray, coeffs: jnp.ndarray,
+                 runs: Sequence[Tuple[int, ...]], io_dtype,
+                 d_in: Optional[jnp.ndarray] = None,
+                 d_out: Optional[jnp.ndarray] = None,
+                 bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``spm_full_ref`` with the stage stack split into ``runs`` (the
+    stride tuples of a run plan, e.g. from ``ops.plan_runs``) and the
+    activation stored in ``io_dtype`` between runs, as the fused path
+    hands one kernel's output to the next.  Autodiff through that cast
+    rounds the cotangent to ``io_dtype`` at the same boundaries, as the
+    fused backward stores each run's input cotangent.  With an f32
+    ``io_dtype`` this is ``spm_full_ref``.
+
+    The rounding is ``lax.reduce_precision``, not a cast round trip: XLA
+    on TPU may drop a ``f32 -> bf16 -> f32`` convert pair as excess
+    precision, which would leave this oracle unrounded."""
+    fi = jnp.finfo(io_dtype)
+    z = x if d_in is None else x * d_in.astype(x.dtype)
+    off = 0
+    for r, strides in enumerate(runs):
+        if r and fi.bits < jnp.finfo(x.dtype).bits:
+            z = lax.reduce_precision(z, exponent_bits=fi.nexp,
+                                     mantissa_bits=fi.nmant)
+        z = spm_stack_ref(z, coeffs[off: off + len(strides)], strides)
+        off += len(strides)
     if d_out is not None:
         z = z * d_out.astype(z.dtype)
     if bias is not None:

@@ -84,18 +84,20 @@ the fully-live interior shards that bound the step wall-clock anyway).
 
 Layout notes (TPU-native adaptation of the paper's CPU loop):
   * The feature axis rides the 128-wide lane dimension; batch rides sublanes.
-  * A stride-s stage is the relayout (bb, n) -> (bb, g, 2, s) + vectorized
-    2x2 FMA on the VPU (the MXU would be >97% idle at k=2, so we stay off it).
-  * Stages with s >= 128 are lane-aligned relayouts (free-ish).  Stages with
-    s < 128 induce intra-lane shuffles; the benchmark harness quantifies the
-    residual cost and the two_level schedule orders them first so they fuse
-    while the tile is hot.
+  * Lane-major stage mix: a stride-s stage is
+    ``y = w_self * z + w_partner * z[partner]`` with two (1, n_tile) weight
+    rows per stage (``lane_major`` lays the (L, n/2, 4) pair table out as a
+    (2L, n) slab in XLA; ``pair_table`` maps the grads back).  The partner
+    values are two lane rotations (``pltpu.roll`` by s and n_tile - s) and a
+    select on the lane parity — VPU/XLU work with no relayout.  (A reshape
+    to (bb, g, 2, s) is what interpret mode accepts and Mosaic refuses.)
   * Grid tiles: (batch_tile, feature_tile).  A feature tile of width n_t can
     fuse every stage with n_t % (2 s) == 0 (pair stays inside the tile);
     ops.py splits the schedule into maximal tile-local runs and composes.
 
-Validated in interpret mode on CPU against kernels/ref.py (this container
-has no TPU); the BlockSpec tiling is sized for v5e VMEM (~16 MiB budget).
+Validated in interpret mode against kernels/ref.py;
+tests/test_tpu_compile.py compiles the main path's kernels for a
+described v5e, and chip_smoke.py runs them on the chip.
 """
 
 from __future__ import annotations
@@ -112,7 +114,7 @@ __all__ = ["spm_stack_kernel_call", "spm_stack_bwd_kernel_call",
            "spm_overlap_kernel_call", "spm_overlap_bwd_kernel_call",
            "spm_block_kernel_call", "spm_block_bwd_kernel_call",
            "pick_block_rows", "vmem_bytes", "overlap_vmem_bytes",
-           "block_vmem_bytes"]
+           "block_vmem_bytes", "lane_major", "pair_table"]
 
 _F32 = jnp.float32
 
@@ -127,30 +129,78 @@ def _mask_cols(z, tile_idx, width: int):
     return jnp.where(col < width, z, 0.0)
 
 
+def lane_major(coeffs: jax.Array, strides: Tuple[int, ...]) -> jax.Array:
+    """(L, n//2, 4) pair table -> (2L, n) lane-major coefficient slab.
+
+    Stage ``l`` pairs lane ``lo`` with ``hi = lo + s``; the pair's block
+    ``[[a, b], [c, d]]`` becomes two per-lane weights so that the stage is
+    ``y = w_self * z + w_partner * z[partner(lane)]``: row ``2l`` holds
+    ``w_self`` (a on lo lanes, d on hi lanes), row ``2l + 1`` holds
+    ``w_partner`` (b on lo lanes, c on hi lanes).  Pure layout (slices,
+    reverse, transpose) in XLA, O(nL); the kernels never see the pair
+    table, whose 4-wide minor axis would fight the 128-lane tiling."""
+    n = 2 * coeffs.shape[1]
+    rows = []
+    for ell, s in enumerate(strides):
+        t = coeffs[ell].reshape(n // (2 * s), s, 2, 2)      # [g, k, r, c]
+        u = jnp.stack([t[:, :, 0, :], t[:, :, 1, ::-1]], axis=2)
+        rows.append(jnp.transpose(u, (3, 0, 2, 1)).reshape(2, n))
+    return jnp.concatenate(rows, axis=0)
+
+
+def pair_table(slab: jax.Array, strides: Tuple[int, ...]) -> jax.Array:
+    """Inverse of ``lane_major``: (2L, n) -> (L, n//2, 4).  Maps the
+    kernels' lane-major coefficient grads back onto the parameter
+    layout."""
+    n = slab.shape[1]
+    out = []
+    for ell, s in enumerate(strides):
+        w = slab[2 * ell: 2 * ell + 2].reshape(2, n // (2 * s), 2, s)
+        u = jnp.transpose(w, (1, 3, 2, 0))                  # [g, k, r, w]
+        t = jnp.stack([u[:, :, 0, :], u[:, :, 1, ::-1]], axis=2)
+        out.append(t.reshape(n // 2, 4))
+    return jnp.stack(out)
+
+
+def _partner(z, s: int):
+    """``z`` at each lane's stride-``s`` partner, on a resident (rows, nt)
+    tile whose pairs are tile-local (``nt % (2s) == 0``): lanes with
+    ``(lane // s) % 2 == 0`` read ``lane + s``, the others ``lane - s``.
+    Two lane rotations and a select on the VPU/XLU — no relayout."""
+    nt = z.shape[-1]
+    ax = z.ndim - 1
+    if 2 * s == nt:                     # both rotations coincide
+        return pltpu.roll(z, s, ax)
+    lane = jax.lax.broadcasted_iota(jnp.int32, z.shape, ax)
+    if s & (s - 1) == 0:
+        lo = (lane & s) == 0
+    else:
+        lo = jax.lax.rem(lane, 2 * s) < s
+    return jnp.where(lo, pltpu.roll(z, nt - s, ax), pltpu.roll(z, s, ax))
+
+
+def _stage_weights(cf_ref, ell: int, scf_ref=None):
+    """Stage ``ell``'s (w_self, w_partner) rows, each (1, nt) f32, from a
+    lane-major coefficient slab; ``scf_ref`` ((L, 1) per-stage scales)
+    dequantizes an int8 slab here, in VMEM."""
+    w_self = cf_ref[2 * ell: 2 * ell + 1, :].astype(_F32)
+    w_part = cf_ref[2 * ell + 1: 2 * ell + 2, :].astype(_F32)
+    if scf_ref is not None:
+        w_self = w_self * scf_ref[ell, 0]
+        w_part = w_part * scf_ref[ell, 0]
+    return w_self, w_part
+
+
 def _apply_stages_fwd(z, cf_ref, strides, collect: bool = False,
                       scf_ref=None):
     """Run all stages on a resident f32 tile; optionally collect inputs.
-    With ``scf_ref`` ((L, 1) per-stage scales) the coefficient slab is an
-    int8 table dequantized here, in VMEM, one stage at a time."""
-    bb, nt = z.shape
+    ``cf_ref`` is a (2L, nt) lane-major slab (``lane_major``)."""
     zs = []
     for ell, s in enumerate(strides):
         if collect:
             zs.append(z)
-        g = nt // (2 * s)
-        zr = z.reshape(bb, g, 2, s)
-        cf = cf_ref[ell].astype(_F32)          # (nt//2, 4)
-        if scf_ref is not None:
-            cf = cf * scf_ref[ell, 0]
-        a = cf[:, 0].reshape(g, 1, s)
-        b = cf[:, 1].reshape(g, 1, s)
-        c = cf[:, 2].reshape(g, 1, s)
-        d = cf[:, 3].reshape(g, 1, s)
-        x0 = zr[:, :, 0, :].reshape(bb, g, 1, s)
-        x1 = zr[:, :, 1, :].reshape(bb, g, 1, s)
-        y0 = a * x0 + b * x1
-        y1 = c * x0 + d * x1
-        z = jnp.concatenate([y0, y1], axis=2).reshape(bb, nt)
+        w_self, w_part = _stage_weights(cf_ref, ell, scf_ref)
+        z = w_self * z + w_part * _partner(z, s)
     return (z, zs) if collect else z
 
 
@@ -318,6 +368,11 @@ def pick_max_tile(n: int, n_stages: int, dtype_bytes: int = 4,
     return cap
 
 
+def _last_block(width: int, n_tile: int) -> int:
+    """Index of the last feature block of a ``width``-wide operand."""
+    return -(-width // n_tile) - 1
+
+
 def _vec_spec(n_tile: int) -> pl.BlockSpec:
     """(1, n_tile) slab of an (1, n) vector, indexed by the feature tile."""
     return pl.BlockSpec((1, n_tile), lambda i, j: (0, j))
@@ -397,11 +452,14 @@ def spm_stack_kernel_call(x: jax.Array, coeffs: jax.Array,
     grid = (B // block_rows, n // n_tile if has_base
             else -(-out_w // n_tile))
 
-    # Pair indices for feature tile j are the contiguous slab
-    # [j * n_tile/2, (j+1) * n_tile/2): groups are sequential in the flat
-    # pair index, and each tile covers whole groups for every fused stride.
-    x_spec = pl.BlockSpec((block_rows, n_tile), lambda i, j: (i, j))
-    cf_spec = pl.BlockSpec((L, n_tile // 2, 4), lambda i, j: (0, j, 0))
+    # Lanes of feature tile j are the slab columns [j * n_tile,
+    # (j+1) * n_tile): each tile covers whole pair groups for every fused
+    # stride.  x blocks wholly past a narrow input clamp onto its last
+    # block (the in-VMEM mask zero-fills them), so no DMA leaves the array.
+    x_last = _last_block(x.shape[-1], n_tile)
+    x_spec = pl.BlockSpec((block_rows, n_tile),
+                          lambda i, j: (i, jnp.minimum(j, x_last)))
+    cf_spec = pl.BlockSpec((2 * L, n_tile), lambda i, j: (0, j))
     o_spec = pl.BlockSpec((block_rows, n_tile), lambda i, j: (i, j))
     sc_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
     scf_spec = pl.BlockSpec((L, 1), lambda i, j: (0, 0))
@@ -411,7 +469,7 @@ def spm_stack_kernel_call(x: jax.Array, coeffs: jax.Array,
     if quant_in:
         operands.append(x_scale.astype(_F32))
         in_specs.append(sc_spec)
-    operands.append(coeffs)
+    operands.append(lane_major(coeffs, strides))
     in_specs.append(cf_spec)
     if coeff_scale is not None:
         operands.append(coeff_scale.astype(_F32).reshape(L, 1))
@@ -442,8 +500,9 @@ def spm_stack_kernel_call(x: jax.Array, coeffs: jax.Array,
         # the x map consumes it (blocks past the operand edge clamp; the
         # in-VMEM mask against the global column zero-fills them).
         in_specs = [_lift_spec(s) for s in in_specs]
-        in_specs[0] = pl.BlockSpec(x_spec.block_shape,
-                                   lambda i, j, b: (i, b[0] + j))
+        in_specs[0] = pl.BlockSpec(
+            x_spec.block_shape,
+            lambda i, j, b: (i, jnp.minimum(b[0] + j, x_last)))
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -487,37 +546,38 @@ def _stage_walk_bwd(zs, delta, cf_ref, strides: Tuple[int, ...],
                     scf_ref=None):
     """Reverse walk over one run's stages from the collected stage-input
     tiles ``zs``: the eq. 14 pair grads (reduced over the batch-tile rows)
-    and delta <- B^T delta (eqs. 12-13).  Returns ``(delta_0,
-    gcf (L, nt//2, 4))`` — shared by the plain and overlap backward
-    kernels.  ``scf_ref`` dequantizes an int8 coefficient slab in VMEM
-    (the gcf output stays f32 in DEQUANTIZED units — the grads of the
-    values the forward actually used)."""
-    bb, nt = delta.shape
-    gcf_parts = []
+    and delta <- B^T delta (eqs. 12-13), in the lane-major form
+
+        g_self    = sum_rows delta * z
+        g_partner = sum_rows delta * z[partner]
+        delta    <- w_self * delta + (w_partner * delta)[partner]
+
+    Returns ``(delta_0, rows)`` with ``rows`` the 2L (1, nt) grad rows in
+    slab order (``_acc_rows`` stores them) — shared by the plain, block
+    and overlap backward kernels.  ``scf_ref`` dequantizes an int8
+    coefficient slab in VMEM (the grads stay f32 in DEQUANTIZED units —
+    the grads of the values the forward actually used)."""
+    rows = [None] * (2 * len(strides))
     for ell in range(len(strides) - 1, -1, -1):
         s = strides[ell]
-        g = nt // (2 * s)
-        cf = cf_ref[ell].astype(_F32)
-        if scf_ref is not None:
-            cf = cf * scf_ref[ell, 0]
-        a = cf[:, 0].reshape(g, 1, s)
-        b = cf[:, 1].reshape(g, 1, s)
-        c = cf[:, 2].reshape(g, 1, s)
-        d = cf[:, 3].reshape(g, 1, s)
-        zr = zs[ell].reshape(bb, g, 2, s)
-        dr = delta.reshape(bb, g, 2, s)
-        x0 = zr[:, :, 0, :].reshape(bb, g, 1, s)
-        x1 = zr[:, :, 1, :].reshape(bb, g, 1, s)
-        d0 = dr[:, :, 0, :].reshape(bb, g, 1, s)
-        d1 = dr[:, :, 1, :].reshape(bb, g, 1, s)
-        ga = jnp.sum(d0 * x0, axis=0).reshape(g * s)
-        gb = jnp.sum(d0 * x1, axis=0).reshape(g * s)
-        gc = jnp.sum(d1 * x0, axis=0).reshape(g * s)
-        gd = jnp.sum(d1 * x1, axis=0).reshape(g * s)
-        gcf_parts.append(jnp.stack([ga, gb, gc, gd], axis=-1))
-        delta = jnp.concatenate([a * d0 + c * d1, b * d0 + d * d1],
-                                axis=2).reshape(bb, nt)
-    return delta, jnp.stack(gcf_parts[::-1], axis=0)
+        w_self, w_part = _stage_weights(cf_ref, ell, scf_ref)
+        z = zs[ell]
+        rows[2 * ell] = jnp.sum(delta * z, axis=0, keepdims=True)
+        rows[2 * ell + 1] = jnp.sum(delta * _partner(z, s), axis=0,
+                                    keepdims=True)
+        delta = w_self * delta + _partner(w_part * delta, s)
+    return delta, rows
+
+
+def _acc_rows(ref, rows, first):
+    """Accumulate (1, nt) grad rows into a lane-major slab output across
+    consecutive revisits of its block: zeroed on the ``first`` visit."""
+    @pl.when(first)
+    def _init():
+        ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    for r, row in enumerate(rows):
+        ref[r: r + 1, :] += row
 
 
 def _bwd_kernel(*refs,
@@ -591,14 +651,14 @@ def _bwd_kernel(*refs,
     else:
         delta = gy
 
-    delta, gcf = _stage_walk_bwd(zs, delta, cf_ref, strides,
-                                 scf_ref=scf_ref)
+    delta, g_rows = _stage_walk_bwd(zs, delta, cf_ref, strides,
+                                    scf_ref=scf_ref)
 
     if has_din:
         _acc(gdin_ref, jnp.sum(delta * x_raw, axis=0).reshape(1, nt))
         delta = delta * din_ref[...].astype(_F32)
     gx_ref[...] = delta.astype(gx_ref.dtype)
-    _acc(gcf_ref, gcf)                                 # (L, nt//2, 4)
+    _acc_rows(gcf_ref, g_rows, i == 0)                 # (2L, nt)
 
 
 @functools.partial(jax.jit, static_argnames=("strides", "block_rows",
@@ -701,24 +761,35 @@ def spm_stack_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
     # feature tile only) are revisited on consecutive iterations, which is
     # required for the in-block accumulation to be valid on real TPU.
     grid = (vis, B // block_rows)
+
+    def read_spec(width, windowed=False):
+        # input blocks wholly past a narrow operand clamp onto its last
+        # block (masked to zeros in VMEM); outputs never clamp
+        last = _last_block(width, n_tile)
+        if windowed:
+            return pl.BlockSpec((block_rows, n_tile), lambda j, i, b: (
+                i, jnp.minimum(b[0] + j, last)))
+        return pl.BlockSpec((block_rows, n_tile),
+                            lambda j, i: (i, jnp.minimum(j, last)))
+
     act_spec = pl.BlockSpec((block_rows, n_tile), lambda j, i: (i, j))
-    cf_spec = pl.BlockSpec((L, n_tile // 2, 4), lambda j, i: (0, j, 0))
+    cf_spec = pl.BlockSpec((2 * L, n_tile), lambda j, i: (0, j))
     vec_spec = pl.BlockSpec((1, n_tile), lambda j, i: (0, j))
     sc_spec = pl.BlockSpec((1, 1), lambda j, i: (i, j))
     scf_spec = pl.BlockSpec((L, 1), lambda j, i: (0, 0))
 
     operands = [x]
-    in_specs = [act_spec]
+    in_specs = [read_spec(x.shape[-1])]
     if quant_in:
         operands.append(x_scale.astype(jnp.float32))
         in_specs.append(sc_spec)
-    operands.append(coeffs)
+    operands.append(lane_major(coeffs, strides))
     in_specs.append(cf_spec)
     if coeff_scale is not None:
         operands.append(coeff_scale.astype(jnp.float32).reshape(L, 1))
         in_specs.append(scf_spec)
     operands.append(gy)
-    in_specs.append(act_spec)
+    in_specs.append(read_spec(gy.shape[-1]))
     for vec in (d_in, d_out):
         if vec is not None:
             operands.append(vec.reshape(1, n))
@@ -727,7 +798,7 @@ def spm_stack_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
     gx_dt = gy.dtype if quant_in else x.dtype
     out_specs = [act_spec, cf_spec]
     out_shape = [jax.ShapeDtypeStruct((B, gx_w), gx_dt),
-                 jax.ShapeDtypeStruct((L, n // 2, 4), jnp.float32)]
+                 jax.ShapeDtypeStruct((2 * L, n), jnp.float32)]
     for present in (d_in is not None, d_out is not None, has_bias):
         if present:
             out_specs.append(vec_spec)
@@ -760,14 +831,12 @@ def spm_stack_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
     if has_base:
         # Scalar prefetch: every index map gains a trailing base ref; only
         # the windowed operands consume it (offset feature block).
-        win_spec = pl.BlockSpec((block_rows, n_tile),
-                                lambda j, i, b: (i, b[0] + j))
         in_specs = [_lift_spec(s) for s in in_specs]
         gy_idx = 2 + (1 if coeff_scale is not None else 0)
         if x_windowed:
-            in_specs[0] = win_spec
+            in_specs[0] = read_spec(x.shape[-1], windowed=True)
         if gy_windowed:
-            in_specs[gy_idx] = win_spec
+            in_specs[gy_idx] = read_spec(gy.shape[-1], windowed=True)
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -787,7 +856,7 @@ def spm_stack_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
             input_output_aliases=aliases,
             interpret=interpret,
         )(*operands)
-    gx, gcf = out[0], out[1]
+    gx, gcf = out[0], pair_table(out[1], strides)
     vec_grads = tuple(v.reshape(n) for v in out[2:])
     return (gx, gcf) + vec_grads
 
@@ -993,8 +1062,8 @@ def _block_bwd_kernel(*refs,
             _acc(gbias2_ref, jnp.sum(gy, axis=0).reshape(1, nt))
         _acc(gdout2_ref, jnp.sum(gy * z2_last, axis=0).reshape(1, nt))
         delta = gy * dout2_ref[...].astype(_F32)
-        delta, gcf2 = _stage_walk_bwd(zs2, delta, cf2_ref, strides2)
-        _acc(gcf2_ref, gcf2)
+        delta, g_rows2 = _stage_walk_bwd(zs2, delta, cf2_ref, strides2)
+        _acc_rows(gcf2_ref, g_rows2, i == 0)
         _acc(gdin2_ref, jnp.sum(delta * h, axis=0).reshape(1, nt))
         dh = _mask_cols(delta * din2_ref[...].astype(_F32), 0, mid_width)
         du = dh * _act_grad(u, activation)
@@ -1006,8 +1075,8 @@ def _block_bwd_kernel(*refs,
         _acc(gbias1_ref, jnp.sum(du, axis=0).reshape(1, nt))
     _acc(gdout1_ref, jnp.sum(du * z1_last, axis=0).reshape(1, nt))
     delta = du * dout1_ref[...].astype(_F32)
-    delta, gcf1 = _stage_walk_bwd(zs1, delta, cf1_ref, strides1)
-    _acc(gcf1_ref, gcf1)
+    delta, g_rows1 = _stage_walk_bwd(zs1, delta, cf1_ref, strides1)
+    _acc_rows(gcf1_ref, g_rows1, i == 0)
     _acc(gdin1_ref, jnp.sum(delta * z0, axis=0).reshape(1, nt))
     dz0 = _mask_cols(delta * din1_ref[...].astype(_F32), 0, in_width)
     if has_norm:
@@ -1070,19 +1139,21 @@ def spm_block_kernel_call(x: jax.Array, coeffs1: jax.Array,
     vec_spec = pl.BlockSpec((1, n), lambda i: (0, 0))
 
     def _cf_spec(L):
-        return pl.BlockSpec((L, n // 2, 4), lambda i: (0, 0, 0))
+        return pl.BlockSpec((2 * L, n), lambda i: (0, 0))
 
     operands, in_specs = [x], [row_spec]
     if has_norm:
         operands.append(gamma.reshape(1, n))
         in_specs.append(vec_spec)
-    operands += [coeffs1, d_in1.reshape(1, n), d_out1.reshape(1, n)]
+    operands += [lane_major(coeffs1, strides1), d_in1.reshape(1, n),
+                 d_out1.reshape(1, n)]
     in_specs += [_cf_spec(L1), vec_spec, vec_spec]
     if bias1 is not None:
         operands.append(bias1.reshape(1, n))
         in_specs.append(vec_spec)
     if strides2 is not None:
-        operands += [coeffs2, d_in2.reshape(1, n), d_out2.reshape(1, n)]
+        operands += [lane_major(coeffs2, strides2), d_in2.reshape(1, n),
+                     d_out2.reshape(1, n)]
         in_specs += [_cf_spec(coeffs2.shape[0]), vec_spec, vec_spec]
         if bias2 is not None:
             operands.append(bias2.reshape(1, n))
@@ -1158,19 +1229,21 @@ def spm_block_bwd_kernel_call(x: jax.Array, gy: jax.Array,
     rs_spec = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
 
     def _cf_spec(L):
-        return pl.BlockSpec((L, n // 2, 4), lambda i: (0, 0, 0))
+        return pl.BlockSpec((2 * L, n), lambda i: (0, 0))
 
     operands, in_specs = [x], [row_spec]
     if has_norm:
         operands += [gamma.reshape(1, n), rstd.astype(jnp.float32)]
         in_specs += [vec_spec, rs_spec]
-    operands += [coeffs1, d_in1.reshape(1, n), d_out1.reshape(1, n)]
+    operands += [lane_major(coeffs1, strides1), d_in1.reshape(1, n),
+                 d_out1.reshape(1, n)]
     in_specs += [_cf_spec(L1), vec_spec, vec_spec]
     if bias1 is not None:
         operands.append(bias1.reshape(1, n))
         in_specs.append(vec_spec)
     if strides2 is not None:
-        operands += [coeffs2, d_in2.reshape(1, n), d_out2.reshape(1, n)]
+        operands += [lane_major(coeffs2, strides2), d_in2.reshape(1, n),
+                     d_out2.reshape(1, n)]
         in_specs += [_cf_spec(coeffs2.shape[0]), vec_spec, vec_spec]
         if bias2 is not None:
             operands.append(bias2.reshape(1, n))
@@ -1187,19 +1260,22 @@ def spm_block_bwd_kernel_call(x: jax.Array, gy: jax.Array,
         out_specs.append(vec_spec)
         out_shape.append(jax.ShapeDtypeStruct((1, n), jnp.float32))
 
+    cf_outs = {}                                   # output slot -> strides
+
+    def _cf_out(L, strides):
+        cf_outs[len(out_specs)] = strides
+        out_specs.append(_cf_spec(L))
+        out_shape.append(jax.ShapeDtypeStruct((2 * L, n), jnp.float32))
+
     if has_norm:
         _vec_out()                                 # g_gamma
-    out_specs.append(_cf_spec(L1))
-    out_shape.append(jax.ShapeDtypeStruct((L1, n // 2, 4), jnp.float32))
+    _cf_out(L1, strides1)
     _vec_out()                                     # g_din1
     _vec_out()                                     # g_dout1
     if bias1 is not None:
         _vec_out()
     if strides2 is not None:
-        L2 = coeffs2.shape[0]
-        out_specs.append(_cf_spec(L2))
-        out_shape.append(jax.ShapeDtypeStruct((L2, n // 2, 4),
-                                              jnp.float32))
+        _cf_out(coeffs2.shape[0], strides2)
         _vec_out()                                 # g_din2
         _vec_out()                                 # g_dout2
         if bias2 is not None:
@@ -1219,9 +1295,10 @@ def spm_block_bwd_kernel_call(x: jax.Array, gy: jax.Array,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands))
-    # flatten the (1, n) vector grads to (n,); cf grads (ndim 3) stay
-    return (out[0],) + tuple(v.reshape(n) if v.ndim == 2 else v
-                             for v in out[1:])
+    # flatten the (1, n) vector grads to (n,); cf grads back to pair tables
+    return (out[0],) + tuple(
+        pair_table(v, cf_outs[o]) if o in cf_outs else v.reshape(n)
+        for o, v in enumerate(out) if o > 0)
 
 
 # ---------------------------------------------------------------------------
@@ -1293,6 +1370,22 @@ def _rdma_descriptor(send_buf, recv_buf, send_sem, recv_sem, slot,
         device_id_type=pltpu.DeviceIdType.MESH)
 
 
+def _partner_barrier(partner_ref, mesh_ndim: int, i):
+    """Entry handshake on the kernel's barrier semaphore (allocated by
+    ``collective_id``): before the first remote copy, signal the partner
+    and wait for its signal, so no block lands in VMEM recv slots of a
+    partner that has not entered this kernel yet.  XOR pairing is
+    symmetric, so each device receives exactly one signal."""
+    @pl.when(i == 0)
+    def _():
+        sem = pltpu.get_barrier_semaphore()
+        pltpu.semaphore_signal(sem, inc=1,
+                               device_id=_partner_device_id(partner_ref,
+                                                            mesh_ndim),
+                               device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_wait(sem, 1)
+
+
 def _slot_reuse_guard(rdma, cap_sem, slot, i):
     """Flow control before reusing slot ``i % 2`` at iteration ``i >= 2``:
     our own send from this slot must have drained AND the partner must
@@ -1331,6 +1424,7 @@ def _overlap_kernel(partner_ref, base_ref, *refs,
     o_ref, send_buf, recv_buf, send_sem, recv_sem, cap_sem = refs
 
     i = pl.program_id(0)
+    _partner_barrier(partner_ref, mesh_ndim, i)
 
     def _rdma(slot):
         return _rdma_descriptor(send_buf, recv_buf, send_sem, recv_sem,
@@ -1425,16 +1519,18 @@ def spm_overlap_kernel_call(x: jax.Array, coeffs: jax.Array,
             else jnp.zeros((1,), jnp.int32))
 
     nbm1 = nb - 1
+    x_last = _last_block(x.shape[-1], n_tile)
     x_spec = pl.BlockSpec(
         (block_rows, n_tile),
         lambda i, p, b: (jnp.minimum(i, nbm1),
-                         b[0] if in_width is not None else 0))
-    cf_spec = pl.BlockSpec((L, n_tile // 2, 4), lambda i, p, b: (0, 0, 0))
+                         jnp.minimum(b[0], x_last) if in_width is not None
+                         else 0))
+    cf_spec = pl.BlockSpec((2 * L, n_tile), lambda i, p, b: (0, 0))
     vec_spec = pl.BlockSpec((1, n_tile), lambda i, p, b: (0, 0))
     o_spec = pl.BlockSpec((block_rows, n_tile),
                           lambda i, p, b: (jnp.maximum(i - 1, 0), 0))
 
-    operands = [x, coeffs]
+    operands = [x, lane_major(coeffs, strides)]
     in_specs = [x_spec, cf_spec]
     if coeff_scale is not None:
         operands.append(coeff_scale.astype(jnp.float32).reshape(L, 1))
@@ -1471,7 +1567,7 @@ def spm_overlap_kernel_call(x: jax.Array, coeffs: jax.Array,
                 pltpu.SemaphoreType.REGULAR,                  # credits
             ]),
         out_shape=jax.ShapeDtypeStruct((B, n_tile), io_dt),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(partner.astype(jnp.int32), base, *operands)
 
@@ -1500,6 +1596,7 @@ def _overlap_bwd_kernel(partner_ref, base_ref, *refs,
 
     i = pl.program_id(0)
     bb, nt = gy_ref.shape
+    _partner_barrier(partner_ref, mesh_ndim, i)
 
     def _rdma(slot):
         return _rdma_descriptor(send_buf, recv_buf, send_sem, recv_sem,
@@ -1567,9 +1664,9 @@ def _overlap_bwd_kernel(partner_ref, base_ref, *refs,
         z0 = x_raw * din_ref[...].astype(_F32) if has_din else x_raw
         _, zs = _apply_stages_fwd(z0, cf_ref, strides, collect=True,
                                   scf_ref=scf_ref)
-        delta0, gcf = _stage_walk_bwd(zs, dmid, cf_ref, strides,
-                                      scf_ref=scf_ref)
-        _acc(gcf_ref, gcf)
+        delta0, g_rows = _stage_walk_bwd(zs, dmid, cf_ref, strides,
+                                         scf_ref=scf_ref)
+        _acc_rows(gcf_ref, g_rows, i == 1)
         if has_din:
             _acc(gdin_ref, jnp.sum(delta0 * x_raw, axis=0).reshape(1, nt))
             delta0 = delta0 * din_ref[...].astype(_F32)
@@ -1640,7 +1737,9 @@ def spm_overlap_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
             else jnp.zeros((1,), jnp.int32))
 
     nbm1 = nb - 1
-    x_col = (lambda b: b[0]) if in_width is not None else (lambda b: 0)
+    x_last = _last_block(x.shape[-1], n_tile)
+    x_col = ((lambda b: jnp.minimum(b[0], x_last)) if in_width is not None
+             else (lambda b: 0))
     x_send_spec = pl.BlockSpec(
         (block_rows, n_tile),
         lambda i, p, b: (jnp.minimum(i, nbm1), x_col(b)))
@@ -1649,12 +1748,12 @@ def spm_overlap_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
         lambda i, p, b: (jnp.maximum(i - 1, 0), x_col(b)))
     gy_spec = pl.BlockSpec((block_rows, n_tile),
                            lambda i, p, b: (jnp.minimum(i, nbm1), 0))
-    cf_spec = pl.BlockSpec((L, n_tile // 2, 4), lambda i, p, b: (0, 0, 0))
+    cf_spec = pl.BlockSpec((2 * L, n_tile), lambda i, p, b: (0, 0))
     vec_spec = pl.BlockSpec((1, n_tile), lambda i, p, b: (0, 0))
     gx_spec = pl.BlockSpec((block_rows, n_tile),
                            lambda i, p, b: (jnp.maximum(i - 1, 0), 0))
 
-    operands = [x, x, coeffs]
+    operands = [x, x, lane_major(coeffs, strides)]
     in_specs = [x_send_spec, x_walk_spec, cf_spec]
     if coeff_scale is not None:
         operands.append(coeff_scale.astype(jnp.float32).reshape(L, 1))
@@ -1674,7 +1773,7 @@ def spm_overlap_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
 
     out_specs = [gx_spec, cf_spec, vec_spec, vec_spec]
     out_shape = [jax.ShapeDtypeStruct((B, n_tile), io_dt),
-                 jax.ShapeDtypeStruct((L, n_tile // 2, 4), jnp.float32),
+                 jax.ShapeDtypeStruct((2 * L, n_tile), jnp.float32),
                  jax.ShapeDtypeStruct((1, n_tile), jnp.float32),
                  jax.ShapeDtypeStruct((1, n_tile), jnp.float32)]
     if d_out is not None:
@@ -1704,10 +1803,11 @@ def spm_overlap_bwd_kernel_call(x: jax.Array, coeffs: jax.Array,
                 pltpu.SemaphoreType.REGULAR,                    # credits
             ]),
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=collective_id),
     )(partner.astype(jnp.int32), base, *operands)
-    gx, gcf, s_own, s_swp = out[0], out[1], out[2], out[3]
+    gx, gcf, s_own, s_swp = (out[0], pair_table(out[1], strides), out[2],
+                             out[3])
     res = (gx, gcf, s_own.reshape(n_tile), s_swp.reshape(n_tile))
     rest = list(out[4:])
     t_pair = ()

@@ -217,7 +217,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         coll = H.collective_bytes(compiled.as_text())
         mf = model_flops(cfg, shape)
         terms = H.roofline_terms(cost["flops"], cost["bytes_accessed"],
-                                 coll["total"])
+                                 coll["total"], H.peaks(H.V5E))
         rec.update({
             "ok": True, "t_lower_s": round(t_lower, 1),
             "t_compile_s": round(t_compile, 1),

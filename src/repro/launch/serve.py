@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, get_smoke, with_overrides
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import ContinuousBatchingEngine, Request, ServeEngine
 
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--arrival-every", type=int, default=2,
                     help="ticks between request arrivals (continuous mode)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.linear_impl:

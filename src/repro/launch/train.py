@@ -1,22 +1,24 @@
 """End-to-end training driver with recovery orchestration.
 
-CPU-scale by default (smoke configs); on a real cluster the same driver
-runs under ``jax.distributed.initialize()`` with the production mesh
-(see launch/README_MULTIHOST.md).  Features exercised here: deterministic
-resumable data, NaN-guarded steps, atomic keep-N checkpoints with
-verified-integrity restore (corrupt checkpoints are quarantined and the
-restore walks back to the newest valid step), fault-policy rollback that
-coherently rewinds the loop counter / data cursor / LR schedule, and
-``run_with_recovery`` restarts with exponential backoff around the whole
-loop.  ``--chaos-spec`` arms deterministic fault injection
-(train/chaos.py) so every one of those paths can be exercised on demand:
+CPU-scale with ``--smoke``; without it the arch's full published config
+(``chip_smoke.py`` drives qwen3-1.7b this way on one TPU chip).
+Features exercised here: deterministic resumable data, NaN-guarded
+steps, atomic keep-N checkpoints with verified-integrity restore (corrupt
+checkpoints are quarantined and the restore walks back to the newest
+valid step), fault-policy rollback that coherently rewinds the loop
+counter / data cursor / LR schedule, and ``run_with_recovery`` restarts
+with exponential backoff around the whole loop.  ``--chaos-spec`` arms
+deterministic fault injection (train/chaos.py) so every one of those
+paths can be exercised on demand:
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
       --smoke --steps 50 --batch 8 --seq 64 --ckpt-dir /tmp/run1 \
       --chaos-spec 'nan@13+5;corrupt@18:bitflip;preempt@19'
 
 Tests drive the same code through ``train(args)`` (no subprocess
-needed); it returns the final state for parity assertions.
+needed); it returns the final state for parity assertions, and its
+``on_step`` observer sees every step's host metrics, wall time and
+jitted step function.
 """
 
 from __future__ import annotations
@@ -24,7 +26,7 @@ from __future__ import annotations
 import argparse
 import os
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,7 @@ from repro.data.char_corpus import build_corpus
 from repro.data.loader import DeterministicLoader
 from repro.models import causal_lm as LM
 from repro.models import transformer as T
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import OptimizerConfig
 from repro.train import (FaultEventLog, FaultPolicy, RESUME_LATEST,
                          StragglerDetector, latest_valid_step,
@@ -116,9 +119,14 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+# on_step(step, host metrics, wall seconds, jitted step function)
+StepObserver = Callable[[int, dict, float, Callable], None]
+
+
 def train(args: argparse.Namespace,
           event_log: Optional[FaultEventLog] = None,
-          chaos: Optional[ChaosSchedule] = None) -> dict:
+          chaos: Optional[ChaosSchedule] = None,
+          on_step: Optional[StepObserver] = None) -> dict:
     """Run the full training job described by ``args`` and return the
     final train state.  Builds the recovery orchestration: the inner
     ``loop(resume)`` holds all step/rollback logic, ``run_with_recovery``
@@ -126,7 +134,10 @@ def train(args: argparse.Namespace,
 
     ``event_log`` / ``chaos`` override the ones built from ``args``
     (tests pass a shared ChaosSchedule so fire-once state survives a
-    simulated process death across two ``train`` calls)."""
+    simulated process death across two ``train`` calls).  ``on_step(s,
+    metrics, seconds, step_fn)`` is called after every executed step with
+    its host-side metrics, its wall time (dispatch to ``device_get``) and
+    the jitted step function that ran it."""
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.linear_impl:
         cfg = with_overrides(cfg, linear_impl=args.linear_impl)
@@ -232,7 +243,10 @@ def train(args: argparse.Namespace,
             t_step = time.time()
             state, metrics = step_fn(state, batch, poison)
             metrics = jax.device_get(metrics)
-            straggler.observe(s, time.time() - t_step)
+            dt_step = time.time() - t_step
+            straggler.observe(s, dt_step)
+            if on_step is not None:
+                on_step(s, metrics, dt_step, step_fn)
             if metrics.get("skipped"):
                 event_log.emit("skip", step=s, cause="non-finite grads")
             if policy.on_metrics(metrics):
@@ -271,7 +285,9 @@ def train(args: argparse.Namespace,
 
 
 def main() -> None:
-    train(build_parser().parse_args())
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    train(args)
 
 
 if __name__ == "__main__":

@@ -104,7 +104,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import spm as spm_mod
@@ -1006,10 +1005,10 @@ def _sharded_core(plan: ShardPlan, tables, d_in, d_out, bias, x2):
     """x2: (rows, in_width or n) row-major, rows pre-padded to block_rows
     when the kernel path is on.  Returns (rows, out_width or n)."""
     in_specs, y_spec, _ = _fwd_specs(plan)
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(_shard_fwd, plan, collect=False),
         mesh=plan.mesh, in_specs=in_specs, out_specs=y_spec,
-        check_rep=False)
+        check_vma=False)
     y2 = f(tables, d_in, d_out, bias, x2)
     if plan.out_width is not None:
         y2 = y2[:, :plan.out_width]
@@ -1018,10 +1017,10 @@ def _sharded_core(plan: ShardPlan, tables, d_in, d_out, bias, x2):
 
 def _sharded_core_fwd(plan, tables, d_in, d_out, bias, x2):
     in_specs, y_spec, res_specs = _fwd_specs(plan)
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(_shard_fwd, plan, collect=True),
         mesh=plan.mesh, in_specs=in_specs, out_specs=(y_spec, res_specs),
-        check_rep=False)
+        check_vma=False)
     y2, res = f(tables, d_in, d_out, bias, x2)
     if plan.out_width is not None:
         y2 = y2[:, :plan.out_width]
@@ -1042,11 +1041,11 @@ def _sharded_core_bwd(plan, saved, gy2):
         gy2 = jnp.pad(gy2, ((0, 0), (0, plan.n - plan.out_width)))
     out_specs = (y_spec, plan.table_specs(), plan.vec_spec(plan.has_din),
                  plan.vec_spec(plan.has_dout), plan.vec_spec(plan.has_bias))
-    f = shard_map(
+    f = jax.shard_map(
         functools.partial(_shard_bwd, plan),
         mesh=plan.mesh,
         in_specs=in_specs[:4] + (res_specs, y_spec),
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
     g_x2, g_tabs, g_din, g_dout, g_bias = f(tables, d_in, d_out, bias,
                                             res, gy2)
     if plan.in_width is not None:

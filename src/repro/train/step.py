@@ -201,8 +201,6 @@ def make_pod_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
     pmean-reduced either way so the returned values are replicated.
     Extra ``step_kwargs`` (``accum_steps``, ``nan_guard``,
     ``chaos_guard``) pass through to ``make_train_step``."""
-    from jax.experimental.shard_map import shard_map
-
     step = make_train_step(loss_fn, opt_cfg, grad_axis=axis,
                            compress_grads=compress, **step_kwargs)
 
@@ -223,11 +221,11 @@ def make_pod_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig,
     if compress:
         opt_spec["ef"] = P(axis)
     state_spec = {"params": P(), "opt": opt_spec, "step": P()}
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(state_spec, P(axis), P()),
         out_specs=(state_spec, P()),
-        check_rep=False)
+        check_vma=False)
 
     def pod_step(state: dict, batch: Any, poison: Any = None):
         if poison is None:

@@ -464,7 +464,8 @@ else:
         leave the HLO collective-permute-only with the TOTAL permute bytes
         unchanged — re-blocking splits each stage's exchange, it never
         duplicates or re-routes bytes."""
-        from repro.launch.hlo_analysis import sharded_stage_traffic
+        from repro.launch.hlo_analysis import (V5E, peaks,
+                                               sharded_stage_traffic)
         from repro.parallel.spm_shard import pick_row_blocks
         cfg = SPMConfig(n=64, n_stages=8, schedule="two_level", n_shards=8,
                         backward="custom", use_kernel=False, overlap=True,
@@ -475,7 +476,7 @@ else:
         assert len(pick_row_blocks(rows, 1)) > 1
         steps = spm_shard.plan_steps(64, cfg.pairing.strides(), 8)
         model = sharded_stage_traffic(64 // 8, rows, steps, dtype_bytes=4,
-                                      overlap=True)
+                                      overlap=True, hw=peaks(V5E))
         with activation_sharding(_mesh(8), shard_feature=True):
             fwd = jax.jit(lambda p, x: spm_apply(p, x, cfg))
             hlo = fwd.lower(p, x).compile().as_text()
@@ -491,7 +492,8 @@ else:
     def test_permute_traffic_matches_model():
         """The HLO's collective-permute bytes equal the modeled per-stage
         slab exchanges (hlo_analysis.sharded_stage_traffic)."""
-        from repro.launch.hlo_analysis import sharded_stage_traffic
+        from repro.launch.hlo_analysis import (V5E, peaks,
+                                               sharded_stage_traffic)
         cfg = SPMConfig(n=64, n_stages=8, schedule="two_level", n_shards=8,
                         backward="custom", use_kernel=False,
                         use_diag=False, use_bias=False)
@@ -499,7 +501,8 @@ else:
         rows = 16
         x = jax.random.normal(KEY, (rows, 64))
         steps = spm_shard.plan_steps(64, cfg.pairing.strides(), 8)
-        model = sharded_stage_traffic(64 // 8, rows, steps, dtype_bytes=4)
+        model = sharded_stage_traffic(64 // 8, rows, steps, dtype_bytes=4,
+                                      hw=peaks(V5E))
         with activation_sharding(_mesh(8), shard_feature=True):
             fwd = jax.jit(lambda p, x: spm_apply(p, x, cfg))
             cb = collective_bytes(fwd.lower(p, x).compile().as_text())
@@ -662,7 +665,6 @@ else:
         axis-max scale (pmax), the int8 payloads psum in int32, and each
         member dequantizes to the identical replicated result — matching
         the explicit host-side int8-sum reference."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.optim.compression import _amax_scale, psum_compressed
@@ -673,7 +675,7 @@ else:
         g = jnp.stack([(2.0 if i % 2 else 0.01) *
                        jax.random.normal(jax.random.fold_in(KEY, i), (64,))
                        for i in range(N_DEV)])
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             lambda gi: psum_compressed({"w": gi[0]}, "pod")["w"][None],
             mesh=mesh, in_specs=P("pod"), out_specs=P("pod")))
         out = np.asarray(f(g))

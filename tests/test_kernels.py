@@ -15,9 +15,9 @@ from repro.core.eligibility import quant_acts_eligible
 from repro.core.linear import LinearConfig, init_linear, linear_apply
 from repro.core.spm import stage_coeffs
 from repro.kernels import quant as Q
-from repro.kernels.ops import (plan_runs, spm_stack_fused, spm_stack_fused_q8,
-                               tile_cap_for_rows)
-from repro.kernels.ref import (spm_full_ref, spm_stack_grads_ref,
+from repro.kernels.ops import (plan_runs, plan_runs_for_rows, spm_stack_fused,
+                               spm_stack_fused_q8, tile_cap_for_rows)
+from repro.kernels.ref import (spm_full_ref, spm_runs_ref, spm_stack_grads_ref,
                                spm_stack_ref)
 from repro.kernels.spm_stack import (pick_block_rows, spm_stack_bwd_kernel_call,
                                      spm_stack_kernel_call, vmem_bytes)
@@ -110,20 +110,42 @@ def _full_operands(n, L, dkey=7):
 
 
 FULL_SWEEP = [
-    # (B, n, strides, dtype).  The n=4096 case plans to TWO runs (stride
-    # 2048 has pair span 4096 > MAX_TILE): d_in folds into run 0 and
-    # d_out/bias into run 1, exercising the boundary split.
+    # (B, n, strides, dtype).  At a training row count the n=4096 stack
+    # plans to TWO runs (stride 2048 has pair span 4096 > MAX_TILE): d_in
+    # folds into run 0 and d_out/bias into run 1, exercising the boundary
+    # split; in bf16 the activation crosses that boundary in bf16.
     (8, 128, (1, 2, 4, 8, 16, 64), jnp.float32),
     (5, 256, (1, 2, 4, 8, 16, 32, 64, 128), jnp.float32),
     (8, 128, (1, 2, 4, 8, 16, 64), jnp.bfloat16),
     (4, 4096, (1, 2, 4, 8, 1024, 2048), jnp.float32),
+    (16, 4096, (1, 2, 4, 8, 1024, 2048), jnp.bfloat16),
 ]
 
 
+def _full_sweep_ref(x, cf, strides, dtype, **kw):
+    """The full-operator oracle as the fused path runs it at x's row
+    count: f32 inside a run, the activation stored in ``dtype`` between
+    runs (``spm_full_ref`` itself for a single run or f32)."""
+    runs = plan_runs_for_rows(x.shape[-1], tuple(strides), x.shape[0],
+                              jnp.dtype(dtype).itemsize)
+    return spm_runs_ref(x.astype(jnp.float32), cf, [r for r, _ in runs],
+                        dtype, **kw)
+
+
 def test_full_sweep_has_multi_run_case():
-    """Guard: the sweep's big case really is a multi-run plan (so the
-    boundary folding and the per-run backward routing stay covered)."""
-    assert len(plan_runs(4096, (1, 2, 4, 8, 1024, 2048))) == 2
+    """Guard: a sweep case really runs a multi-run plan at its row count
+    (so the boundary folding and the per-run backward routing stay
+    covered), and the run-boundary oracle is ``spm_full_ref`` in f32."""
+    multi = [(B, n, s, dt) for B, n, s, dt in FULL_SWEEP
+             if len(plan_runs_for_rows(n, s, B, jnp.dtype(dt).itemsize)) > 1]
+    assert multi
+    _, n, strides, _ = multi[0]
+    cf, d_in, d_out, bias = _full_operands(n, len(strides))
+    x = jax.random.normal(KEY, (16, n))
+    np.testing.assert_array_equal(
+        _full_sweep_ref(x, cf, strides, jnp.float32, d_in=d_in,
+                        d_out=d_out, bias=bias),
+        spm_full_ref(x, cf, strides, d_in=d_in, d_out=d_out, bias=bias))
 
 
 @pytest.mark.parametrize("B,n,strides,dtype", FULL_SWEEP)
@@ -132,8 +154,8 @@ def test_fused_full_operator_matches_ref(B, n, strides, dtype):
     x = jax.random.normal(KEY, (B, n)).astype(dtype)
     y = spm_stack_fused(x, cf, strides, d_in=d_in, d_out=d_out, bias=bias)
     assert y.dtype == dtype
-    ref = spm_full_ref(x.astype(jnp.float32), cf, tuple(strides),
-                       d_in=d_in, d_out=d_out, bias=bias)
+    ref = _full_sweep_ref(x, cf, strides, dtype, d_in=d_in, d_out=d_out,
+                          bias=bias)
     tol = 1e-4 if dtype == jnp.float32 else 4e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(ref, np.float32),
@@ -144,8 +166,9 @@ def test_fused_full_operator_matches_ref(B, n, strides, dtype):
 def test_fused_full_operator_grads_match_autodiff(B, n, strides, dtype):
     """custom_vjp of the FULL fused operator == autodiff on the unfused
     reference, in every operand: x, coeffs, d_in, d_out, bias — incl. the
-    bf16-activation backward (grads vs a bf16-quantized-forward oracle;
-    param grads stay f32 in-kernel)."""
+    bf16-activation backward (grads vs a bf16-quantized-forward oracle
+    that also rounds the activation, and so the cotangent, at run
+    boundaries; param grads stay f32 in-kernel)."""
     cf, d_in, d_out, bias = _full_operands(n, len(strides))
     x = jax.random.normal(KEY, (B, n)).astype(dtype)
 
@@ -155,8 +178,8 @@ def test_fused_full_operator_grads_match_autodiff(B, n, strides, dtype):
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     def r(x, cf, d_in, d_out, bias):
-        y = spm_full_ref(x.astype(jnp.float32), cf, tuple(strides),
-                         d_in=d_in, d_out=d_out, bias=bias)
+        y = _full_sweep_ref(x, cf, strides, dtype, d_in=d_in, d_out=d_out,
+                            bias=bias)
         return jnp.sum(y ** 2)
 
     g = jax.grad(f, argnums=(0, 1, 2, 3, 4))(x, cf, d_in, d_out, bias)

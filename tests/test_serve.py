@@ -162,25 +162,51 @@ def test_flags_isolate_poisoned_request(smoke_model):
     """A NaN embedding row poisons ONLY the requests whose prompt uses
     that token: their rows are flagged (every decode step re-raises via
     the NaN KV cache) and degrade to the in-range fallback, while a clean
-    request in the same batch stays unflagged.  Untied output projection
-    so the poisoned table row cannot leak into every logit column."""
+    request in the same batch stays unflagged and decodes exactly the
+    tokens it decodes on the clean weights.  Untied output projection so
+    the poisoned table row cannot leak into every logit column.  The
+    poisoned id is one the clean request never reads, in its prompt or
+    in its own greedy continuation: decoding that token would poison it
+    by its own input, not through the batch."""
     cfg, _ = smoke_model
     cfg = dataclasses.replace(cfg, tie_embeddings=False)
     params = T.init_model(KEY, cfg)
-    poisoned = jax.tree.map(lambda x: x, params)
-    poisoned["embed"] = dict(params["embed"])
-    poisoned["embed"]["table"] = \
-        params["embed"]["table"].at[3].set(jnp.nan)
+    clean = jnp.asarray([1, 2, 4, 5], jnp.int32)
+    clean_out = ServeEngine(cfg=cfg, params=params, max_len=16,
+                            cache_dtype=jnp.float32).generate(
+        clean[None], max_new_tokens=4)[0].tolist()
+    ceng = ContinuousBatchingEngine(cfg, params, slots=2, max_len=16)
+    clean_cont = ceng.serve([Request(prompt=clean, max_new_tokens=4,
+                                     rid=1)])[0][1]["tokens"]
+    touched = set(clean.tolist()) | set(clean_out) | set(clean_cont)
+    bad_id = min(set(range(1, cfg.vocab_size)) - touched)
 
+    def poison(tok):
+        p = jax.tree.map(lambda x: x, params)
+        p["embed"] = dict(params["embed"])
+        p["embed"]["table"] = params["embed"]["table"].at[tok].set(jnp.nan)
+        return p
+
+    # Why the id must avoid the clean continuation: id 3 is a token the
+    # clean request decodes and then reads back, so with id 3 poisoned it
+    # is flagged when served ALONE, with no other row to leak from.
+    assert 3 in clean_out[:-1]
+    _, alone_flags = ServeEngine(cfg=cfg, params=poison(3), max_len=16,
+                                 cache_dtype=jnp.float32).generate(
+        clean[None], max_new_tokens=4, return_flags=True)
+    assert bool(alone_flags[0])
+
+    poisoned = poison(bad_id)
     # ServeEngine: flags are the union over prefill + every decode step
     eng = ServeEngine(cfg=cfg, params=poisoned, max_len=16,
                       cache_dtype=jnp.float32)
-    prompts = jnp.stack([jnp.asarray([1, 2, 3, 4], jnp.int32),   # has 3
-                         jnp.asarray([1, 2, 4, 5], jnp.int32)])  # clean
+    prompts = jnp.stack([jnp.asarray([1, 2, bad_id, 4], jnp.int32),
+                         clean])
     out, flags = eng.generate(prompts, max_new_tokens=4,
                               return_flags=True)
     assert bool(flags[0]) and not bool(flags[1])
     np.testing.assert_array_equal(np.asarray(out[0]), 0)  # fallback row
+    assert out[1].tolist() == clean_out
     assert bool(((out >= 0) & (out < cfg.vocab_size)).all())
 
     # continuous engine: per-request ``flagged`` carries the same union
@@ -190,4 +216,5 @@ def test_flags_isolate_poisoned_request(smoke_model):
         Request(prompt=prompts[1], max_new_tokens=4, rid=1)])
     assert results[0]["flagged"] and not results[1]["flagged"]
     assert results[0]["tokens"] == [0, 0, 0, 0]
+    assert results[1]["tokens"] == clean_cont
     assert all(0 <= t < cfg.vocab_size for t in results[1]["tokens"])

@@ -8,7 +8,8 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import get_smoke
-from repro.launch.hlo_analysis import (collective_bytes, parse_shape_bytes,
+from repro.launch.hlo_analysis import (V5E, collective_bytes,
+                                       parse_shape_bytes, peaks,
                                        roofline_terms)
 from repro.launch.specs import abstract_cache, abstract_state, input_specs
 from repro.configs.shapes import SHAPES
@@ -159,9 +160,20 @@ def test_collective_bytes_parsing():
 
 
 def test_roofline_terms_dominance():
-    t = roofline_terms(197e12, 0.0, 0.0)        # 1s of pure compute
+    v5e = peaks(V5E)
+    t = roofline_terms(197e12, 0.0, 0.0, v5e)   # 1s of pure compute
     assert t["dominant"] == "compute_s"
     assert t["roofline_fraction"] == pytest.approx(1.0)
-    t = roofline_terms(1e12, 819e9 * 2, 0.0)    # memory-bound
+    t = roofline_terms(1e12, 819e9 * 2, 0.0, v5e)   # memory-bound
     assert t["dominant"] == "memory_s"
     assert t["roofline_fraction"] < 0.01
+
+
+def test_peaks_keyed_by_device_kind():
+    """The v5e row carries the published Google Cloud "TPU v5e" peaks
+    under the device_kind JAX reports, and a device that is not in the
+    table raises instead of silently modeling v5e."""
+    v5e = peaks("TPU v5 lite")
+    assert (v5e["peak_flops"], v5e["hbm_bw"]) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
