@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import pairings
 from repro.core.eligibility import kernel_eligible, use_fused_kernel
 from repro.core.pairings import Schedule, Stage
@@ -445,6 +446,7 @@ def _cached_core(sched: Schedule, mode: str):
 # here unchanged for back-compat.
 
 
+@obs.scoped("spm")
 def spm_apply(params: dict, x: jax.Array, cfg: SPMConfig, *,
               in_width: Optional[int] = None,
               out_width: Optional[int] = None) -> jax.Array:

@@ -63,6 +63,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.eligibility import (block_fusion_eligible,
                                     quant_acts_eligible, tiny_row_call)
 from repro.kernels import spm_stack as K
@@ -597,6 +598,7 @@ def _block_bwd(statics, res, gy):
 _block_core.defvjp(_block_fwd, _block_bwd)
 
 
+@obs.scoped("spm")
 def spm_block_fused(x: jax.Array, *,
                     coeffs1: jax.Array, d_in1: jax.Array,
                     d_out1: jax.Array, strides1: Sequence[int],

@@ -19,6 +19,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.eligibility import resolve_block_fuse
 from repro.core.linear import (LinearConfig, init_linear, linear_apply,
                                spm_block_operands)
@@ -201,6 +202,7 @@ def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # full layer apply
 # ---------------------------------------------------------------------------
 
+@obs.scoped("attn")
 def attention_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
                     cos: jax.Array, sin: jax.Array,
                     cache: Optional[dict] = None,

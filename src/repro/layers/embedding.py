@@ -13,6 +13,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = ["EmbeddingConfig", "init_embedding", "embed", "unembed"]
 
 
@@ -34,6 +36,7 @@ def init_embedding(key: jax.Array, cfg: EmbeddingConfig) -> dict:
     return p
 
 
+@obs.scoped("embed")
 def embed(params: dict, tokens: jax.Array, cfg: EmbeddingConfig,
           dtype=jnp.float32, onehot: bool = False) -> jax.Array:
     """Token lookup.  ``onehot=True`` lowers as a matmul: with the table

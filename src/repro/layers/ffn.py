@@ -25,6 +25,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.eligibility import block_fusion_eligible, resolve_block_fuse
 from repro.core.linear import (LinearConfig, init_linear, linear_apply,
                                spm_block_operands)
@@ -123,6 +124,7 @@ def _block_bundles(params: dict, cfg: FFNConfig):
     return up, down
 
 
+@obs.scoped("ffn")
 def ffn_block_apply(params: dict, norm_params: Optional[dict], x: jax.Array,
                     cfg: FFNConfig) -> jax.Array:
     """The whole residual block: ``x + ffn(rms_norm(x))``.

@@ -20,6 +20,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.linear import LinearConfig, init_linear, linear_apply
 from repro.layers.norms import init_rms_norm, rms_norm
 
@@ -175,6 +176,7 @@ def _causal_conv(u: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return out + b
 
 
+@obs.scoped("ssm")
 def mamba2_apply(params: dict, x: jax.Array, cfg: Mamba2Config, *,
                  cache: Optional[dict] = None
                  ) -> Tuple[jax.Array, Optional[dict]]:

@@ -18,6 +18,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.layers.ffn import FFNConfig, init_ffn, ffn_apply
 
 __all__ = ["MoEConfig", "init_moe", "moe_apply"]
@@ -104,6 +105,7 @@ def _top_k_gating(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     return gates, mask
 
 
+@obs.scoped("moe")
 def moe_apply(params: dict, x: jax.Array, cfg: MoEConfig
               ) -> Tuple[jax.Array, jax.Array]:
     """x: (B, T, d) -> (y, aux_loss).  aux is the load-balancing loss

@@ -7,6 +7,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import transformer as T
 
 __all__ = ["lm_loss", "train_metrics", "prefill", "decode_step"]
@@ -27,8 +28,9 @@ def lm_loss(params: dict, batch: dict, cfg: T.ModelConfig
     logits, _, aux = T.forward(params, cfg, positions=batch.get("positions"),
                                **kw)
     labels = batch["labels"]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    with obs.scope("loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     mask = batch.get("mask")
     if mask is None:
         mask = jnp.ones_like(labels, jnp.float32)

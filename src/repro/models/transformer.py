@@ -20,6 +20,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.layers.attention import (AttentionConfig, attention_apply,
                                     init_attention, init_kv_cache)
 from repro.layers.embedding import (EmbeddingConfig, embed, init_embedding,
@@ -516,9 +517,10 @@ def forward(params: dict, cfg: ModelConfig, *,
             if cache is not None:
                 new_cache.append(nc)
 
-    h = rms_norm(params["final_norm"], h)
-    logits = unembed(params["embed"], h.astype(cfg.logits_dtype),
-                     cfg.embed_cfg())
+    with obs.scope("lm_head"):
+        h = rms_norm(params["final_norm"], h)
+        logits = unembed(params["embed"], h.astype(cfg.logits_dtype),
+                         cfg.embed_cfg())
     return logits, new_cache, aux_total
 
 

@@ -8,6 +8,8 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 __all__ = ["OptimizerConfig", "init_opt_state", "adamw_update",
            "cosine_schedule", "global_norm", "clip_by_global_norm"]
 
@@ -57,6 +59,7 @@ def init_opt_state(params: Any) -> dict:
             "count": jnp.zeros((), jnp.int32)}
 
 
+@obs.scoped("optimizer")
 def adamw_update(params: Any, grads: Any, state: dict,
                  cfg: OptimizerConfig,
                  lr: Optional[jax.Array] = None) -> Tuple[Any, dict, dict]:

@@ -41,11 +41,14 @@ which requests ever hit the guard so callers can flag/retry them.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import time
 from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import causal_lm as LM
 from repro.models import transformer as T
 
@@ -60,6 +63,7 @@ def serve_step(params: dict, cfg: T.ModelConfig, tokens: jax.Array,
     return LM.decode_step(params, cfg, tokens, cache, cache_index)
 
 
+@obs.scoped("sample")
 def _sample(logits: jax.Array, key: jax.Array, temperature: jax.Array,
             greedy: bool) -> Tuple[jax.Array, jax.Array]:
     """Traced sampling head; returns ``(tokens, bad)`` where ``bad`` is a
@@ -166,6 +170,7 @@ class Request:
     rid: Optional[int] = None
 
 
+@obs.scoped("sample")
 def _sample_rows(logits: jax.Array, keys: jax.Array, temperature: jax.Array,
                  top_k: jax.Array, top_p: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -299,13 +304,22 @@ class ContinuousBatchingEngine:
 
     def _admit(self, batch, tick_idx, results):
         """Bucket-batched prefill of ``batch`` [(slot, Request)], then
-        scatter each prefilled row + its per-request state into its slot."""
-        groups: dict = {}
-        for slot, req in batch:
-            prompt = jnp.asarray(req.prompt, jnp.int32).reshape(-1)
-            groups.setdefault(self._bucket(prompt.shape[0]),
-                              []).append((slot, req, prompt))
-        for bucket, members in sorted(groups.items()):
+        scatter each prefilled row + its per-request state into its slot.
+        Stamps each request's ``admit_s`` and ``first_token_s``."""
+        t_admit = time.time()
+        with obs.span("serve.admit", tick=tick_idx,
+                      rids=[r.rid for _, r in batch]):
+            groups: dict = {}
+            for slot, req in batch:
+                results[req.rid]["admit_s"] = t_admit
+                prompt = jnp.asarray(req.prompt, jnp.int32).reshape(-1)
+                groups.setdefault(self._bucket(prompt.shape[0]),
+                                  []).append((slot, req, prompt))
+            for bucket, members in sorted(groups.items()):
+                self._admit_bucket(bucket, members, tick_idx, results)
+
+    def _admit_bucket(self, bucket, members, tick_idx, results):
+        with obs.span("serve.pack", bucket=bucket):
             toks = jnp.stack(
                 [jnp.pad(p, (0, bucket - p.shape[0])) for _, _, p in members])
             lens = jnp.asarray([p.shape[0] for _, _, p in members], jnp.int32)
@@ -315,9 +329,13 @@ class ContinuousBatchingEngine:
                                jnp.float32)
             topk = jnp.asarray([r.top_k for _, r, _ in members], jnp.int32)
             topp = jnp.asarray([r.top_p for _, r, _ in members], jnp.float32)
+        with obs.span("serve.prefill", bucket=bucket, group=len(members)):
             logits, pcache = self._prefill(self.params, toks, lens)
+        with obs.span("serve.first_token"):
             first, bad = self._sample_first(logits, keys, temp, topk, topp)
             first, bad = jax.device_get((first, bad))
+            t_first = time.time()
+        with obs.span("serve.insert"):
             for g, (slot, req, prompt) in enumerate(members):
                 self._cache = self._insert(self._cache, pcache,
                                            jnp.asarray(g, jnp.int32),
@@ -334,6 +352,7 @@ class ContinuousBatchingEngine:
                 res["tokens"].append(int(first[g]))
                 res["flagged"] |= bool(bad[g])
                 res["admitted_tick"] = tick_idx
+                res["first_token_s"] = t_first
                 self._slot_req[slot] = req
 
     def serve(self, requests, *, arrival_ticks=None):
@@ -342,10 +361,15 @@ class ContinuousBatchingEngine:
         ``arrival_ticks[i]`` (default 0) is the decode tick at which
         request *i* becomes admissible — the knob load generators use to
         model offered load.  Returns ``(results, stats)``: ``results``
-        maps rid -> {tokens, flagged, admitted_tick, finished_tick};
+        maps rid -> {tokens, flagged, admitted_tick, finished_tick,
+        arrival_s, admit_s, first_token_s}, the last three host-clock
+        stamps (``time.time()``): the start of the request's arrival tick
+        (or of the first tick run at or after it), the start of its
+        admission, and its first token's arrival on the host;
         ``stats`` has ``ticks``, ``tokens`` (decoded total incl. prefill
         samples), and ``occupied_slot_ticks`` for occupancy/latency
-        accounting."""
+        accounting.  Each loop iteration's host work lies in the
+        ``obs.SPANS`` spans (docs/serving.md, Observability)."""
         for r in requests:
             if r.rid is None:
                 r.rid = self._next_rid
@@ -359,21 +383,32 @@ class ContinuousBatchingEngine:
                     f"({r.max_new_tokens}) exceeds max_len={self.max_len}")
         arrival_ticks = list(arrival_ticks or [0] * len(requests))
         pending = sorted(zip(arrival_ticks, range(len(requests))))
+        unstamped = list(pending)           # arrivals without arrival_s
         results = {r.rid: {"tokens": [], "flagged": False,
-                           "admitted_tick": None, "finished_tick": None}
+                           "admitted_tick": None, "finished_tick": None,
+                           "arrival_s": None, "admit_s": None,
+                           "first_token_s": None}
                    for r in requests}
         self._reset()
         stats = {"ticks": 0, "tokens": 0, "occupied_slot_ticks": 0}
-        tick_idx = 0
-        while pending or any(r is not None for r in self._slot_req):
-            # admit arrivals into free slots
-            free = [s for s in range(self.slots) if self._slot_req[s] is None]
-            batch = []
-            while pending and free and pending[0][0] <= tick_idx:
-                _, i = pending.pop(0)
-                batch.append((free.pop(0), requests[i]))
+        for tick_idx in itertools.count():
+            with obs.span("serve.schedule"):
+                if not pending and all(r is None for r in self._slot_req):
+                    break
+                now = time.time()
+                while unstamped and unstamped[0][0] <= tick_idx:
+                    results[requests[unstamped.pop(0)[1]].rid][
+                        "arrival_s"] = now
+                # admit arrivals into free slots
+                free = [s for s in range(self.slots)
+                        if self._slot_req[s] is None]
+                batch = []
+                while pending and free and pending[0][0] <= tick_idx:
+                    _, i = pending.pop(0)
+                    batch.append((free.pop(0), requests[i]))
             if batch:
                 self._admit(batch, tick_idx, results)
+            with obs.span("serve.bookkeep"):
                 # a max_new_tokens == 1 admit finishes without decoding
                 for s, req in batch:
                     if len(results[req.rid]["tokens"]) >= req.max_new_tokens:
@@ -381,13 +416,18 @@ class ContinuousBatchingEngine:
                         self._active = self._active.at[s].set(False)
                         self._slot_req[s] = None
                 stats["tokens"] += len(batch)
-            n_active = sum(r is not None for r in self._slot_req)
-            if n_active:
+                stats["ticks"] += 1
+                n_active = sum(r is not None for r in self._slot_req)
+            if not n_active:
+                continue
+            with obs.span("serve.tick", tick=tick_idx):
                 self._tok, bad, self._cache, self._ci, self._steps = \
                     self._tick(self.params, self._tok, self._cache, self._ci,
                                self._active, self._keys, self._steps,
                                self._temp, self._topk, self._topp)
+            with obs.span("serve.fetch"):
                 tok_h, bad_h = jax.device_get((self._tok, bad))
+            with obs.span("serve.bookkeep"):
                 for s in range(self.slots):
                     req = self._slot_req[s]
                     if req is not None:
@@ -400,6 +440,4 @@ class ContinuousBatchingEngine:
                             self._slot_req[s] = None
                 stats["tokens"] += n_active
                 stats["occupied_slot_ticks"] += n_active
-            stats["ticks"] += 1
-            tick_idx += 1
         return results, stats

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.optim.adamw import OptimizerConfig, adamw_update
 from repro.optim.compression import psum_compressed_ef
 
@@ -155,13 +156,14 @@ def make_train_step(loss_fn: Callable, opt_cfg: OptimizerConfig, *,
         metrics = dict(metrics)
         metrics.update(info)
         if nan_guard:
-            ok = jnp.isfinite(info["grad_norm"]) & jnp.isfinite(loss)
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_params,
-                state["params"])
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_opt, state["opt"])
-            metrics["skipped"] = (~ok).astype(jnp.float32)
+            with obs.scope("optimizer"):
+                ok = jnp.isfinite(info["grad_norm"]) & jnp.isfinite(loss)
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_params,
+                    state["params"])
+                new_opt = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new_opt, state["opt"])
+                metrics["skipped"] = (~ok).astype(jnp.float32)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         return new_state, metrics

@@ -31,6 +31,7 @@ API_MODULES = [
     "repro.serve.engine",
     "repro.kernels.quant",
     "repro.optim.compression",
+    "repro.obs",
 ]
 
 DOC_FILES = ["README.md"] + sorted(

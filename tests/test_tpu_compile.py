@@ -8,7 +8,10 @@ at every projection shape of qwen3-1.7b (plus its 2048 -> 4096 fused-qkv
 width) with bf16 activations, at the row block the planner picks for a
 train batch and for a decode tick, and the RDMA overlap kernel pair on a
 4-chip ``("model",)`` mesh.  Each compiled program must hold its
-``tpu_custom_call``.  Nothing runs; a passing compile is not a chip run.
+``tpu_custom_call``.  A small qwen3 train step compiled with the kernels
+on shows every kernel call, forward, recomputed and backward, under the
+``spm`` scope (``repro.obs``).  Nothing runs; a passing compile is not a
+chip run.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and every test worker imports
@@ -189,3 +192,44 @@ def test_rdma_overlap_backward_compiles_for_v5e(mesh):
                       check_vma=False)
     compiled = jax.jit(f).lower(*_pair_shapes(mesh)).compile()
     assert _kernel_count(compiled) == 1
+
+
+def test_train_step_kernels_lie_under_the_spm_scope(one_chip, monkeypatch):
+    """Every SPM kernel of a train step (stack and fused norm -> SPM
+    block kernels; forward, remat recompute and backward) carries the
+    ``spm`` scope in its ``op_name``, so a trace attributes it there."""
+    import dataclasses
+    import re
+
+    from repro import obs
+    from repro.configs import get_smoke
+    from repro.models import causal_lm as LM
+    from repro.models import transformer as T
+    from repro.optim.adamw import OptimizerConfig
+    from repro.train.state import make_train_state
+    from repro.train.step import make_train_step
+
+    cfg = dataclasses.replace(get_smoke("qwen3-1.7b", use_kernel=True),
+                              d_model=256, d_ff=512, n_heads=2,
+                              n_kv_heads=1, head_dim=128, vocab_size=512)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    # the fused norm -> SPM block path engages on a TPU backend only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    state = jax.eval_shape(lambda: make_train_state(
+        T.init_model(jax.random.PRNGKey(0), cfg)))
+    on_chip = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), t)
+    batch = {k: jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    step = jax.jit(make_train_step(lambda p, b: LM.lm_loss(p, b, cfg),
+                                   OptimizerConfig(), chaos_guard=True))
+    text = step.lower(on_chip(state), batch, jax.ShapeDtypeStruct(
+        (), jnp.float32, sharding=one_chip)).compile().as_text()
+    calls = [re.search(r'op_name="([^"]*)"', ln).group(1)
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(obs.scope_of(n) == "spm" for n in calls), calls
+    for kernel in ("spm_stack_kernel_call", "spm_stack_bwd_kernel_call",
+                   "spm_block_kernel_call", "spm_block_bwd_kernel_call"):
+        assert any(f"({kernel})" in n for n in calls), kernel
+    assert any("rematted_computation" in n for n in calls)
