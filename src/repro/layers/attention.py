@@ -5,10 +5,13 @@ operator can replace every dense Q/K/V/O map (paper §7).  The score
 computation ``Q K^T`` is untouched (paper §7.2: "attention score
 computation remains unchanged").
 
-The training/prefill path is an online-softmax over key chunks written
-with ``jax.lax`` control flow: memory is O(T * chunk) instead of O(T^2),
-which is what lets the 32k-prefill dry-run cells fit HBM.  Sliding-window
-(Gemma3 local layers) is a mask refinement of the same loop.
+The training/prefill path (``fresh_causal_attention``) runs the Pallas
+flash kernel of ``kernels/attention.py`` on a TPU wherever the shape
+allows, and otherwise an online-softmax over key chunks written with
+``jax.lax`` control flow (``chunked_causal_attention``): memory is
+O(T * chunk) instead of O(T^2), which is what lets the 32k-prefill
+dry-run cells fit HBM.  Sliding-window (Gemma3 local layers) is a mask
+refinement of the same loop.
 """
 
 from __future__ import annotations
@@ -25,10 +28,11 @@ from repro.core.linear import (LinearConfig, init_linear, linear_apply,
                                spm_block_operands)
 from repro.layers.norms import qk_norm, rms_norm
 from repro.layers.rope import apply_rope
-from repro.parallel.ctx import constrain
+from repro.parallel.ctx import constrain, sharding_active
 
 __all__ = ["AttentionConfig", "init_attention", "attention_apply",
-           "init_kv_cache", "chunked_causal_attention"]
+           "init_kv_cache", "chunked_causal_attention",
+           "fresh_causal_attention"]
 
 NEG_INF = -1e30
 
@@ -198,6 +202,28 @@ def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out
 
 
+def fresh_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           window: Optional[int] = None,
+                           q_offset: int = 0,
+                           q_chunk: int = 1024,
+                           k_chunk: int = 1024) -> jax.Array:
+    """``chunked_causal_attention``'s contract, run as the Pallas flash
+    kernel (``kernels/attention.py``) where it applies: on a TPU, global
+    attention (no window) of a fresh sequence (``q_offset`` 0, as many
+    queries as keys) whose length and head size are multiples of 128,
+    outside an ``activation_sharding`` block (the kernel's ``pallas_call``
+    is not partitioned).  Anywhere else the chunked path runs, unchanged."""
+    from repro.kernels import attention as flash  # lazy: keeps layers light
+    Tq, dh = q.shape[1], q.shape[3]
+    if (jax.default_backend() == "tpu" and window is None and q_offset == 0
+            and Tq == k.shape[1] and flash.supported(Tq, dh)
+            and not sharding_active()):
+        return flash.causal_attention(q, k, v)
+    return chunked_causal_attention(q, k, v, window=window,
+                                    q_offset=q_offset, q_chunk=q_chunk,
+                                    k_chunk=k_chunk)
+
+
 # ---------------------------------------------------------------------------
 # full layer apply
 # ---------------------------------------------------------------------------
@@ -218,9 +244,10 @@ def attention_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
     is applied up front — bitwise the caller-side composition.  Three
     modes:
 
-    * **training** — ``cache is None``: chunked causal attention, no cache.
+    * **training** — ``cache is None``: ``fresh_causal_attention`` (the
+      flash kernel or the chunked path), no cache.
     * **prefill-into-cache** — cache given with ``T > 1``: the fresh
-      prompt runs the SAME chunked attention path and its K/V are
+      prompt runs the SAME attention path and its K/V are
       block-written into the (assumed empty) cache in one pass — no
       per-token scan.  ``cache_index`` is the scalar start position
       (serving prefills at 0); ``fill_len`` (scalar or per-row ``(B,)``)
@@ -282,15 +309,15 @@ def attention_apply(params: dict, x: jax.Array, cfg: AttentionConfig, *,
     k = apply_rope(k, cos, sin)
 
     if cache is None:
-        out = chunked_causal_attention(
+        out = fresh_causal_attention(
             q, k, v, window=cfg.window,
             q_chunk=cfg.q_chunk, k_chunk=cfg.k_chunk)
         new_cache = None
     elif T > 1:
         # prefill-into-cache: attention over the fresh prompt runs the
-        # chunked training path (cache assumed empty), then K/V are
-        # block-written in one pass.
-        out = chunked_causal_attention(
+        # training path (cache assumed empty), then K/V are block-written
+        # in one pass.
+        out = fresh_causal_attention(
             q, k, v, window=cfg.window,
             q_chunk=cfg.q_chunk, k_chunk=cfg.k_chunk)
         kc = k.astype(cache["k"].dtype)

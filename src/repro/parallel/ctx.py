@@ -24,7 +24,8 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["activation_sharding", "constrain", "feature_mesh"]
+__all__ = ["activation_sharding", "constrain", "feature_mesh",
+           "sharding_active"]
 
 _STATE = threading.local()
 
@@ -46,6 +47,11 @@ def activation_sharding(mesh: Mesh, *, shard_heads: bool = True,
         yield
     finally:
         _STATE.ctx = prev
+
+
+def sharding_active() -> bool:
+    """Whether an ``activation_sharding`` block is open."""
+    return _current() is not None
 
 
 def feature_mesh(n_shards: Optional[int] = None) -> Optional[Mesh]:

@@ -10,8 +10,10 @@ train batch and for a decode tick, and the RDMA overlap kernel pair on a
 4-chip ``("model",)`` mesh.  Each compiled program must hold its
 ``tpu_custom_call``.  A small qwen3 train step compiled with the kernels
 on shows every kernel call, forward, recomputed and backward, under the
-``spm`` scope (``repro.obs``).  Nothing runs; a passing compile is not a
-chip run.
+``spm`` scope (``repro.obs``) and every call of the causal attention
+kernel under ``attn``; that kernel is also compiled alone at a
+qwen3-1.7b train row and a qwen3-32b prefill bucket.  Nothing runs; a passing compile is not
+a chip run.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and every test worker imports
@@ -94,7 +96,8 @@ def _operator_args(sharding, rows, d_in, n, strides):
 
 
 def _kernel_count(compiled):
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
 
 
 @pytest.mark.parametrize("rows", [TRAIN_ROWS, DECODE_ROWS],
@@ -128,6 +131,38 @@ def test_fused_backward_compiles_for_v5e(one_chip, name):
         *_operator_args(one_chip, TRAIN_ROWS, d_in, n, strides)).compile()
     runs = ops.plan_runs_for_rows(n, strides, TRAIN_ROWS, dtype_bytes=2)
     assert _kernel_count(compiled) == 2 * len(runs)   # fwd + bwd per run
+
+
+# ---------------------------------------------------------------------------
+# causal flash attention (kernels/attention.py)
+# ---------------------------------------------------------------------------
+
+# (B, T, H, Hkv): a qwen3-1.7b train row; a qwen3-32b prefill bucket of
+# three 1024-token prompts
+ATTN_SHAPES = {"qwen3-1.7b_train": (1, 4096, 16, 8),
+               "qwen3-32b_prefill": (3, 1024, 64, 8)}
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("name", list(ATTN_SHAPES))
+def test_attention_kernel_compiles_for_v5e(one_chip, monkeypatch, name,
+                                           direction):
+    from repro.kernels import attention as flash
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    B, T, H, Hkv = ATTN_SHAPES[name]
+
+    def s(heads):
+        return jax.ShapeDtypeStruct((B, T, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash.causal_attention(q, k, v).astype(jnp.float32))
+
+    fn = (flash.causal_attention if direction == "forward"
+          else jax.grad(loss, argnums=(0, 1, 2)))
+    text = jax.jit(fn).lower(s(H), s(Hkv), s(Hkv)).compile().as_text()
+    # forward: one kernel; backward: forward with residuals, fused dq/dkv
+    assert _kernel_count(text) == (1 if direction == "forward" else 2)
 
 
 # ---------------------------------------------------------------------------
@@ -197,7 +232,9 @@ def test_rdma_overlap_backward_compiles_for_v5e(mesh):
 def test_train_step_kernels_lie_under_the_spm_scope(one_chip, monkeypatch):
     """Every SPM kernel of a train step (stack and fused norm -> SPM
     block kernels; forward, remat recompute and backward) carries the
-    ``spm`` scope in its ``op_name``, so a trace attributes it there."""
+    ``spm`` scope in its ``op_name``, so a trace attributes it there; every
+    call of the attention kernel (forward, remat recompute, fused
+    backward) carries ``attn``."""
     import dataclasses
     import re
 
@@ -225,11 +262,22 @@ def test_train_step_kernels_lie_under_the_spm_scope(one_chip, monkeypatch):
                                    OptimizerConfig(), chaos_guard=True))
     text = step.lower(on_chip(state), batch, jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=one_chip)).compile().as_text()
+    # splash prints its kernel metadata (block sizes) on lines of its own
+    text = re.sub(r"kernel_metadata=\{\n.*\n\}", "kernel_metadata={}", text)
     calls = [re.search(r'op_name="([^"]*)"', ln).group(1)
              for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert calls and all(obs.scope_of(n) == "spm" for n in calls), calls
+    spm = [n for n in calls if "spm_" in n]
+    assert spm and all(obs.scope_of(n) == "spm" for n in spm), spm
     for kernel in ("spm_stack_kernel_call", "spm_stack_bwd_kernel_call",
                    "spm_block_kernel_call", "spm_block_bwd_kernel_call"):
-        assert any(f"({kernel})" in n for n in calls), kernel
-    assert any("rematted_computation" in n for n in calls)
+        assert any(f"({kernel})" in n for n in spm), kernel
+    assert any("rematted_computation" in n for n in spm)
+    # the attention kernel (T 256 takes it): forward, remat recompute and
+    # fused backward, each under ``attn``
+    attn = [n for n in calls if "splash_mqa_" in n]
+    assert attn and all(obs.scope_of(n) == "attn" for n in attn), attn
+    assert len(spm) + len(attn) == len(calls)
+    assert any("splash_mqa_dkv" in n for n in attn)
+    assert any("rematted_computation" in n and "splash_mqa_fwd" in n
+               for n in attn)
