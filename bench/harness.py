@@ -135,10 +135,33 @@ def read_per_layer(cell: spec.Cell, host: dict, tr: trace_mod.Trace,
         v = spec.metric_reader(cell, m["name"])(ctx)
         if v is not None:
             out[m["name"]] = v
-        elif not tr.stand_in:
+        elif tr.stand_in:
+            continue
+        elif may_be_silent(m["name"]):
+            log(f"per-layer metric {m['name']} read nothing: its kernel "
+                f"did not run in the traced stretch; left out of the line")
+        else:
             raise RuntimeError(f"per-layer metric {m['name']} read nothing "
                                f"from the traced stretch")
     return out
+
+
+def may_be_silent(metric: str) -> bool:
+    """A kernel's roofline (``<kernel>_roofline.<phase>``) reads nothing
+    once a program takes that kernel off the path; every other per-layer
+    metric reads on every traced run of its cells."""
+    return metric.split(".", 1)[0].endswith("_roofline")
+
+
+def stall_note(what: str, seconds: List[float]) -> str:
+    """A line on the window's steadiness: the median and the longest of
+    its steps or tick intervals, and where the longest fell."""
+    if not seconds:
+        return f"{what} seconds: none in the window"
+    i = max(range(len(seconds)), key=seconds.__getitem__)
+    med = sorted(seconds)[len(seconds) // 2]
+    return (f"{what} seconds: median {med:.4f} longest {seconds[i]:.4f} "
+            f"(number {i} of {len(seconds)}, {sum(seconds[:i]):.2f}s in)")
 
 
 def trace_summary(tr: trace_mod.Trace) -> dict:

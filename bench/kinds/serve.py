@@ -423,6 +423,9 @@ def run(cell: spec.Cell, args, *, t_start, counter, tracer, span, log,
     log(f"window {window:.3f}s drain {w['t_end'] - w['t_close']:.3f}s "
         f"requests {w['attempted']} tokens {w['tokens']} "
         f"list_exhausted {w['complete']} setup {setup_s:.2f}s")
+    starts = sorted(t for t in srv.probe.tick_start.values()
+                    if w["t0"] <= t <= w["t_close"])
+    log(harness.stall_note("tick", np.diff(starts).tolist()))
     host = layer_stamps(srv.probe, w["reqs"], tracer, window)
     srv.free_engine()
     gc.collect()

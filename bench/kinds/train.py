@@ -203,13 +203,16 @@ def run(cell: spec.Cell, args, *, t_start, counter, tracer, span, log,
     t0 = time.time()
     setup_s = t0 - t_start
     n = skipped = traced = 0
+    step_s = []
     # a traced run also finishes its traced steps when they end past
     # the deadline (a slow step on a loaded host)
     while (time.time() < t0 + args.seconds
            or (tracer.enabled and not tracer.done)):
         if n == mix["trace_after_steps"]:
             tracer.start()
+        ts = time.time()
         m = prog.step(s, span)
+        step_s.append(time.time() - ts)
         traced += tracer.active
         skipped += int(m.get("skipped", 0) > 0)
         n += 1
@@ -224,6 +227,7 @@ def run(cell: spec.Cell, args, *, t_start, counter, tracer, span, log,
     dev = harness.device_info(cell.chips)
     log(f"window {window:.3f}s steps {n} tokens/s {tokens / window:.1f} "
         f"setup {setup_s:.2f}s")
+    log(harness.stall_note("step", step_s))
 
     prog.state = prog.call = prog.step_fn = None
     del prog

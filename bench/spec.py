@@ -3,12 +3,14 @@
 ``BENCHMARK.json`` (root of the checkout) names each cell's configuration
 and traffic mix.  A configuration is ``configs/<file>.json``: the
 published ``config.json`` keys as run, the program's registry arch and
-overrides that realise it, and the file of its plain reference.  A
-traffic mix is ``traffic/<mix>.json``; its ``kind`` picks the module
-``kinds/<kind>.py``.  A per-layer metric is ``metrics/<metric>.py`` with
-a ``read(ctx)`` that returns a number or ``None``.  The limits of a
-cell's correctness numbers are ``limits/<cell>.json``.  Adding any of
-these is adding a file; no file here names a cell.
+overrides that realise it, and the file of its plain reference; where
+needed, the published keys no program field realises and the program
+fields it requires (``program_config``).  A traffic mix is
+``traffic/<mix>.json``; its ``kind`` picks the module ``kinds/<kind>.py``.
+A per-layer metric is ``metrics/<metric>.py`` with a ``read(ctx)`` that
+returns a number or ``None``.  The limits of a cell's correctness numbers
+are ``limits/<cell>.json``.  Adding any of these is adding a file; no
+file here names a cell.
 """
 
 from __future__ import annotations
@@ -23,13 +25,19 @@ from typing import Any, List, Optional
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 
-# published key -> program ModelConfig field
+# published key -> program ModelConfig field, for keys every configuration
+# states
 PROGRAM_FIELDS = {
     "hidden_size": "d_model", "num_hidden_layers": "n_layers",
     "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
     "head_dim": "head_dim", "intermediate_size": "d_ff",
     "vocab_size": "vocab_size", "tie_word_embeddings": "tie_embeddings",
     "rope_theta": "rope_theta",
+}
+# the same, for keys checked wherever a configuration states them
+STATED_FIELDS = {
+    "num_experts": "n_experts", "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "moe_d_ff",
 }
 
 
@@ -125,9 +133,36 @@ def published(cell: Cell) -> dict:
             if isinstance(v, (int, float, str, bool))}
 
 
+PROGRAM_KEYS = ("arch", "overrides", "require", "unmapped")
+
+
+def field_map(cell: Cell) -> dict:
+    """Published key -> program field, as ``program_config`` checks it:
+    ``PROGRAM_FIELDS`` and the ``STATED_FIELDS`` the file states, less the
+    file's ``program.unmapped`` (key -> why no program field realises
+    it)."""
+    prog = cell.config["program"]
+    unknown = set(prog) - set(PROGRAM_KEYS)
+    if unknown:
+        raise ValueError(f"program keys {sorted(unknown)} unknown; "
+                         f"known: {PROGRAM_KEYS}")
+    unmapped = prog.get("unmapped", {})
+    if not (isinstance(unmapped, dict) and all(
+            isinstance(v, str) and v.strip() for v in unmapped.values())):
+        raise ValueError(f"program.unmapped maps each published key to "
+                         f"the reason no program field realises it; got "
+                         f"{unmapped!r}")
+    out = dict(PROGRAM_FIELDS)
+    out.update((k, f) for k, f in STATED_FIELDS.items()
+               if k in cell.config)
+    return {k: f for k, f in out.items() if k not in unmapped}
+
+
 def program_config(cell: Cell):
     """The program's ModelConfig for this configuration: the registry
-    arch with the file's overrides, checked against the published keys."""
+    arch with the file's overrides, checked against the published keys
+    (``field_map``) and the file's ``program.require`` (program field ->
+    value it must hold)."""
     from repro.configs import get_config
     prog = cell.config["program"]
     cfg = get_config(prog["arch"])
@@ -135,13 +170,15 @@ def program_config(cell: Cell):
     if "n_layers" in over:
         over["layers"] = tuple(cfg.layers[: over["n_layers"]])
     cfg = dataclasses.replace(cfg, **over)
-    for key, field in PROGRAM_FIELDS.items():
+    for key, field in field_map(cell).items():
         want = cell.config[key]
         got = getattr(cfg, field)
         if (float(got) if isinstance(want, float) else got) != want:
             raise ValueError(f"program config {field}={got!r} differs from "
                              f"the published {key}={want!r}")
-    if not cfg.qk_norm:
-        raise ValueError("Qwen3 applies RMS qk-norm; the program config "
-                         "has qk_norm off")
+    for field, want in prog.get("require", {}).items():
+        got = getattr(cfg, field)
+        if got != want:
+            raise ValueError(f"program config {field}={got!r}; the "
+                             f"configuration requires {want!r}")
     return cfg

@@ -80,3 +80,31 @@ def test_trace_without_device_plane_is_refused(tmp_path):
         T.load(str(tmp_path))
     tr = T.load(str(tmp_path), cpu_stand_in=True)
     assert tr.stand_in and list(tr.device_ops) == ["/host:CPU"]
+
+
+@pytest.mark.parametrize("metric, silent_ok", [
+    ("spm_roofline.train", True), ("attn_roofline.train", True),
+    ("attn_share.train", False), ("spm_share.train", False)])
+def test_a_reader_that_reads_nothing_on_a_device_trace(tmp_path, metric,
+                                                      silent_ok):
+    """On a device trace, only a kernel's roofline may read nothing (its
+    kernel left the path); any other silent reader fails the run."""
+    import dataclasses
+
+    import harness
+    import spec
+    cell = spec.load_cell("tiny.train", root=tiny_cells.make(str(tmp_path)))
+    cell = dataclasses.replace(cell, per_layer=[
+        m for m in cell.per_layer if m["name"] == metric])
+    tr = T.Trace({"/device:TPU:0": ops(("fusion.1", 0.0, 1.0))},
+                 [T.Span("bench.window", 0.0, 2.0)])
+    host = {"device_kind": "TPU v5 lite", "steps_traced": 1, "batch": 2,
+            "seq": 32}
+    assert harness.may_be_silent(metric) == silent_ok
+    if silent_ok:
+        assert harness.read_per_layer(cell, host, tr, cell.kind) == {}
+    else:
+        with pytest.raises(RuntimeError, match=metric):
+            harness.read_per_layer(cell, host, tr, cell.kind)
+    stand_in = dataclasses.replace(tr, stand_in=True)
+    assert harness.read_per_layer(cell, host, stand_in, cell.kind) == {}

@@ -28,8 +28,35 @@ TINY = {
     "program": {"arch": "qwen3-1.7b", "overrides": {
         "d_model": 64, "d_ff": 96, "n_layers": 2, "n_heads": 4,
         "n_kv_heads": 2, "head_dim": 16, "vocab_size": 256,
-        "q_chunk": 16, "k_chunk": 16}},
+        "q_chunk": 16, "k_chunk": 16},
+        "require": {"qk_norm": True}},
     "reference": "qwen3_reference.py",
+}
+
+# a mixture-of-experts configuration at the widths of the zoo's qwen3-moe
+# SMOKE, keyed as Qwen3-30B-A3B's config.json: its expert keys are checked
+# as stated; every FFN is sparse, so the published intermediate_size has
+# no program field
+TINY_MOE = {
+    "model_type": "qwen3_moe", "hidden_size": 64, "intermediate_size": 192,
+    "moe_intermediate_size": 32, "num_experts": 8, "num_experts_per_tok": 2,
+    "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "num_hidden_layers": 2, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
+    "tie_word_embeddings": False, "rope_theta": 1000000,
+    "rms_norm_eps": 1e-06,
+    "program": {"arch": "qwen3-moe-30b-a3b", "overrides": {
+        "d_model": 64, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
+        "head_dim": 16, "vocab_size": 256, "n_experts": 8, "top_k": 2,
+        "moe_d_ff": 32, "tie_embeddings": False,
+        "q_chunk": 16, "k_chunk": 16},
+        "unmapped": {
+            "intermediate_size": "every layer's FFN is a mixture of "
+                                 "experts; no dense FFN is held",
+            "norm_topk_prob": "the program's gating always renormalises "
+                              "over the top k; the reference follows the "
+                              "published flag, and the check compares"},
+        "require": {"qk_norm": True}},
 }
 
 TRAIN = {"kind": "train", "batch": 2, "seq": 32,

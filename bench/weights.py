@@ -7,7 +7,8 @@ program nor its reference supplies the other's weights:
 * ``mix`` (SPM coefficients, ``(..., L, n/2, 4)``): a random rotation
   ``(cos t, -sin t, sin t, cos t)`` per pair plus 0.05 normal noise;
 * ``d_in``, ``d_out``, ``scale``, ``q_norm``, ``k_norm``: 1 + 0.1 normal;
-* ``table``, ``out`` (embedding, untied head): 0.02 normal.
+* ``table``, ``out`` (embedding, untied head), ``router`` (a mixture of
+  experts' dense router): 0.02 normal.
 
 An unknown leaf is an error: the reference would not know it either.
 """
@@ -20,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 ONE_PLUS = ("d_in", "d_out", "scale", "q_norm", "k_norm")
-NORMAL_002 = ("table", "out")
+NORMAL_002 = ("table", "out", "router")
 
 
 def leaf_name(path) -> str:
