@@ -1,5 +1,6 @@
 """qwen3-moe-30b-a3b [moe]: 48L d=2048 32H (GQA kv=4) per-expert d_ff=768
-vocab=151936, MoE 128 experts top-8 [hf:Qwen/Qwen3-30B-A3B; hf]."""
+vocab=151936, MoE 128 experts top-8, router_aux_loss_coef 0.001
+[hf:Qwen/Qwen3-30B-A3B; hf]."""
 
 from repro.configs.base import moe_layers
 from repro.models.transformer import ModelConfig
@@ -8,14 +9,14 @@ CONFIG = ModelConfig(
     name="qwen3-moe-30b-a3b", d_model=2048, n_layers=48, n_heads=32,
     n_kv_heads=4, head_dim=128, d_ff=0, vocab_size=151936,
     layers=moe_layers(48), scan_group=1, qk_norm=True,
-    n_experts=128, top_k=8, moe_d_ff=768,
+    n_experts=128, top_k=8, moe_d_ff=768, moe_aux_coef=0.001, moe_aux="hf",
     rope_theta=1e6, linear_impl="spm_general", spm_backward="custom")
 
 SMOKE = ModelConfig(
     name="qwen3-moe-smoke", d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
     head_dim=16, d_ff=0, vocab_size=256,
     layers=moe_layers(2), scan_group=1, qk_norm=True,
-    n_experts=8, top_k=2, moe_d_ff=32,
+    n_experts=8, top_k=2, moe_d_ff=32, moe_aux_coef=0.001, moe_aux="hf",
     rope_theta=1e6, linear_impl="spm_general", spm_backward="custom",
     dtype="float32", q_chunk=16, k_chunk=16)
 

@@ -1,13 +1,25 @@
-"""Mixture-of-Experts FFN with GShard-style capacity dispatch.
+"""Mixture-of-Experts FFN with dropless routing over row tiles.
 
-The dispatch/combine einsums are the expert-parallel (EP) communication
-pattern: with experts sharded over the ``model`` mesh axis and tokens over
-``data``, XLA's SPMD partitioner lowers them to all-to-alls.  The router
-stays a dense ``d -> E`` map — it is a tiny classifier head, not a square
-feature mixer, so SPM is inapplicable by design (DESIGN.md §4).
+The router is a dense ``d -> E`` map in f32 — a tiny classifier head, not
+a square feature mixer, so SPM is inapplicable by design (DESIGN.md §4).
+Each token picks its ``top_k`` experts; the gates are the softmax over
+all experts renormalised over the chosen ones.
 
-Per-expert FFN weights DO route through the linear factory, so SPM applies
-inside each expert (``vmap`` over the expert axis).
+Dispatch never drops a token.  The ``N * k`` (token, expert) assignments
+are sorted by expert, and each expert's group is padded to a multiple of
+a row tile ``t`` (``ROW_TILE``, less for small batches) inside one
+static buffer of ``N * k + E * (t - 1)`` rows (rounded up to whole
+tiles) — the most the padded groups can take.  Every tile belongs to one
+expert (``tile_expert``); tiles past the last group hold zeros and are
+never combined.  The expert SwiGLU runs through the ordinary
+``ffn_apply`` / ``linear_apply`` path, ``vmap``-ped over tiles with each
+tile's parameters gathered from its expert, so SPM experts run the fused
+SPM kernel (custom_vjp) and autodiff of the gather sums each tile's
+parameter gradient into its expert.  The outputs are gathered back per
+assignment and combined with the gates in f32.
+
+The layer also returns the per-expert routing statistics that the
+load-balancing loss needs and counters of the dispatch.
 """
 
 from __future__ import annotations
@@ -21,7 +33,11 @@ import jax.numpy as jnp
 from repro import obs
 from repro.layers.ffn import FFNConfig, init_ffn, ffn_apply
 
-__all__ = ["MoEConfig", "init_moe", "moe_apply"]
+__all__ = ["MoEConfig", "init_moe", "moe_apply", "dispatch_plan",
+           "load_balancing_loss"]
+
+MIN_TILE = 8      # row tile floor: one sublane group of the SPM kernel
+ROW_TILE = 128    # row tile at training sizes (a power of two)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,11 +46,6 @@ class MoEConfig:
     d_ff: int                 # per-expert hidden dim
     n_experts: int
     top_k: int
-    capacity_factor: float = 1.25
-    group_size: int = 512     # GShard "S": dispatch is computed per token
-                              # group, so the one-hot tensor is
-                              # (G, S, E, C) with C ~ k*S/E — total memory
-                              # O(N * k * S), NOT O(N * E * C_global).
     shared_d_ff: int = 0      # Llama4-style always-on shared expert (0 = off)
     linear_impl: str = "dense"
     spm_stages: Optional[int] = None
@@ -47,9 +58,8 @@ class MoEConfig:
     spm_quant_coeffs: bool = False
     param_dtype: Any = jnp.float32
 
-    @property
-    def expert_ffn(self) -> FFNConfig:
-        return FFNConfig(d_model=self.d_model, d_ff=self.d_ff,
+    def _ffn(self, d_ff: int) -> FFNConfig:
+        return FFNConfig(d_model=self.d_model, d_ff=d_ff,
                          linear_impl=self.linear_impl,
                          spm_stages=self.spm_stages,
                          spm_backward=self.spm_backward,
@@ -60,25 +70,24 @@ class MoEConfig:
                          spm_quant_acts=self.spm_quant_acts,
                          spm_quant_coeffs=self.spm_quant_coeffs,
                          param_dtype=self.param_dtype)
+
+    @property
+    def expert_ffn(self) -> FFNConfig:
+        return self._ffn(self.d_ff)
 
     @property
     def shared_ffn(self) -> FFNConfig:
-        return FFNConfig(d_model=self.d_model, d_ff=self.shared_d_ff,
-                         linear_impl=self.linear_impl,
-                         spm_stages=self.spm_stages,
-                         spm_backward=self.spm_backward,
-                         spm_use_kernel=self.spm_use_kernel,
-                         spm_schedule=self.spm_schedule,
-                         spm_n_shards=self.spm_n_shards,
-                         spm_overlap=self.spm_overlap,
-                         spm_quant_acts=self.spm_quant_acts,
-                         spm_quant_coeffs=self.spm_quant_coeffs,
-                         param_dtype=self.param_dtype)
+        return self._ffn(self.shared_d_ff)
 
-    def capacity(self, group_tokens: int) -> int:
-        c = int(self.capacity_factor * self.top_k * group_tokens
-                / self.n_experts)
-        return max(c, self.top_k)
+    def tile_rows(self, n_tokens: int) -> int:
+        """The row tile for ``n_tokens`` tokens: ``ROW_TILE``, or less
+        when the mean expert load is smaller (decode), so the buffer's
+        padding ``E * (t - 1)`` stays near the routed rows."""
+        mean = -(-n_tokens * self.top_k // self.n_experts)
+        t = MIN_TILE
+        while t < min(mean, ROW_TILE):
+            t *= 2
+        return t
 
 
 def init_moe(key: jax.Array, cfg: MoEConfig) -> dict:
@@ -94,67 +103,86 @@ def init_moe(key: jax.Array, cfg: MoEConfig) -> dict:
     return p
 
 
-def _top_k_gating(logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
-    """logits (..., E) -> (gates (..., E) renormalized over chosen, mask)."""
-    topv, topi = jax.lax.top_k(logits, k)
-    probs = jax.nn.softmax(topv, axis=-1)                 # renorm over top-k
-    onehot = jax.nn.one_hot(topi, logits.shape[-1],
-                            dtype=logits.dtype)           # (..., k, E)
-    gates = jnp.einsum("...k,...ke->...e", probs, onehot)
-    mask = jnp.sum(onehot, axis=-2) > 0
-    return gates, mask
+def dispatch_plan(experts: jax.Array, n_experts: int, tile: int) -> dict:
+    """Where each assignment goes in the tile buffer.
+
+    ``experts``: (A,) expert id of each assignment.  Returns ``dest`` (A,)
+    its buffer row, ``src`` (R,) the assignment each buffer row holds (A
+    for padding), ``tile_expert`` (R / tile,) the expert of each tile and
+    ``counts`` (E,) the rows routed to each expert, with
+    ``R = ceil((A + E * (tile - 1)) / tile) * tile``."""
+    A = experts.shape[0]
+    n_tiles = -(-(A + n_experts * (tile - 1)) // tile)
+    counts = jnp.bincount(experts, length=n_experts)
+    tiles = -(-counts // tile)
+    tile_end = jnp.cumsum(tiles)                       # (E,) in tiles
+    start = (tile_end - tiles) * tile                  # first row per expert
+    first = jnp.cumsum(counts) - counts                # sorted offset
+    order = jnp.argsort(experts, stable=True)
+    e_sorted = experts[order]
+    dest_sorted = start[e_sorted] + jnp.arange(A) - first[e_sorted]
+    dest = jnp.zeros((A,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32), unique_indices=True)
+    src = jnp.full((n_tiles * tile,), A, jnp.int32).at[dest].set(
+        jnp.arange(A, dtype=jnp.int32), unique_indices=True)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+        n_experts - 1).astype(jnp.int32)
+    return {"dest": dest, "src": src, "tile_expert": tile_expert,
+            "counts": counts}
+
+
+def load_balancing_loss(frac: jax.Array, prob: jax.Array) -> jax.Array:
+    """HF ``load_balancing_loss_func``: ``E * sum_e frac_e * prob_e`` with
+    ``frac_e`` the share of tokens that chose expert ``e`` (summing to
+    ``top_k``) and ``prob_e`` its mean router probability, both averaged
+    over every routed token of every layer."""
+    return frac.shape[-1] * jnp.sum(frac * prob)
 
 
 @obs.scoped("moe")
 def moe_apply(params: dict, x: jax.Array, cfg: MoEConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, T, d) -> (y, aux_loss).  aux is the load-balancing loss
-    (Switch-style mean(gate_frac * token_frac) * E).
+              ) -> Tuple[jax.Array, dict]:
+    """x: (B, T, d) -> (y, stats).
 
-    GShard grouped dispatch: tokens are split into G groups of S; routing
-    capacity is per (group, expert), so the dispatch one-hot is
-    (G, S, E, C) with C = ceil(cf * k * S / E).  With G sharded over
-    ``data`` and experts over ``model``, the two einsums below lower to
-    the canonical EP all-to-all pair.
-    """
+    ``stats``: ``frac`` and ``prob`` (E,), this layer's share of tokens
+    per expert and mean router probability (``load_balancing_loss``);
+    ``switch``, this layer's load-balancing loss over ``top_k``;
+    ``rows``, the routed assignments; ``pad_rows`` and ``buffer_rows``,
+    the rows padding the experts' groups to whole tiles and the buffer's
+    rows; ``load_max``, the largest expert's rows over the mean."""
     B, T, d = x.shape
-    n_tok = B * T
-    S = min(cfg.group_size, n_tok)
-    while n_tok % S:
-        S -= 1
-    G = n_tok // S
-    cap = cfg.capacity(S)
+    N, E, k = B * T, cfg.n_experts, cfg.top_k
+    xt = x.reshape(N, d)
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topv, topi = jax.lax.top_k(probs, k)                  # (N, k)
+    gates = topv / jnp.sum(topv, axis=-1, keepdims=True)  # renormalised
 
-    xg = x.reshape(G, S, d)
-    logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    gates, mask = _top_k_gating(logits, cfg.top_k)        # (G, S, E)
-
-    # load-balancing aux loss (global means)
-    me = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=(0, 1))
-    ce = jnp.mean(mask.astype(jnp.float32),
-                  axis=(0, 1)) * cfg.n_experts / cfg.top_k
-    aux = jnp.sum(me * ce)
-
-    # capacity-limited positions: rank within (group, expert)
-    maskf = mask.astype(jnp.int32)
-    pos = jnp.cumsum(maskf, axis=1) - 1                   # (G, S, E)
-    keep = mask & (pos < cap)
-    gates = jnp.where(keep, gates, 0.0)
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, -1), cap,
-                            dtype=x.dtype)                # (G, S, E, C)
-
-    dispatch = pos_oh
-    combine = gates.astype(x.dtype)[..., None] * pos_oh
-
-    xe = jnp.einsum("gsec,gsd->egcd", dispatch, xg)       # EP all-to-all
-    E = cfg.n_experts
-    ye = jax.vmap(lambda p, h: ffn_apply(p, h, cfg.expert_ffn)
-                  )(params["experts"], xe.reshape(E, G * cap, d))
-    ye = ye.reshape(E, G, cap, d)
-    yg = jnp.einsum("gsec,egcd->gsd", combine, ye)        # EP all-to-all
-
-    y = yg.reshape(B, T, d)
+    tile = cfg.tile_rows(N)
+    plan = dispatch_plan(topi.reshape(-1), E, tile)
+    tok = jnp.concatenate([jnp.arange(N * k) // k,
+                           jnp.array([N])])[plan["src"]]
+    xbuf = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])[tok]
+    n_tiles = plan["tile_expert"].shape[0]
+    tile_params = jax.tree.map(
+        lambda a: a.at[plan["tile_expert"]].get(indices_are_sorted=True),
+        params["experts"])
+    ybuf = jax.vmap(lambda p, h: ffn_apply(p, h, cfg.expert_ffn))(
+        tile_params, xbuf.reshape(n_tiles, tile, d)).reshape(-1, d)
+    ya = ybuf[plan["dest"]].reshape(N, k, d).astype(jnp.float32)
+    y = jnp.sum(gates[..., None] * ya, axis=1).reshape(B, T, d)
     if cfg.shared_d_ff:
         y = y + ffn_apply(params["shared"], x, cfg.shared_ffn)
-    return y.astype(x.dtype), aux
+
+    counts = plan["counts"].astype(jnp.float32)
+    padded = jnp.sum(-(-plan["counts"] // tile) * tile).astype(jnp.float32)
+    frac, prob = counts / N, jnp.mean(probs, axis=0)
+    stats = {"frac": frac, "prob": prob,
+             "switch": load_balancing_loss(frac, prob) / k,
+             "rows": jnp.float32(N * k), "pad_rows": padded - N * k,
+             "buffer_rows": jnp.float32(n_tiles * tile),
+             "load_max": jnp.max(counts) * E / (N * k)}
+    return y.astype(x.dtype), stats

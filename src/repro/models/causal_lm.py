@@ -12,8 +12,6 @@ from repro.models import transformer as T
 
 __all__ = ["lm_loss", "train_metrics", "prefill", "decode_step"]
 
-MOE_AUX_COEF = 0.01
-
 
 def lm_loss(params: dict, batch: dict, cfg: T.ModelConfig
             ) -> Tuple[jax.Array, dict]:
@@ -37,8 +35,8 @@ def lm_loss(params: dict, batch: dict, cfg: T.ModelConfig
     mask = mask.astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     ce = jnp.sum(nll * mask) / denom
-    loss = ce + MOE_AUX_COEF * aux
-    metrics = {"loss": loss, "ce": ce, "aux": aux,
+    loss = ce + cfg.moe_aux_coef * aux["aux"]
+    metrics = {"loss": loss, "ce": ce, **aux,
                "ppl_proxy": jnp.exp(jnp.clip(ce, max=20.0)),
                # mask weight of this batch: lets gradient accumulation
                # recover the global masked mean from per-microbatch means

@@ -28,7 +28,8 @@ from repro.layers.embedding import (EmbeddingConfig, embed, init_embedding,
 from repro.layers.ffn import FFNConfig, ffn_block_apply, init_ffn
 from repro.layers.mamba2 import (Mamba2Config, init_mamba2, init_ssm_cache,
                                  mamba2_apply)
-from repro.layers.moe import MoEConfig, init_moe, moe_apply
+from repro.layers.moe import (MoEConfig, init_moe, load_balancing_loss,
+                              moe_apply)
 from repro.layers.norms import init_rms_norm, rms_norm
 from repro.layers.rope import mrope_angles, rope_angles
 from repro.parallel.ctx import constrain
@@ -71,7 +72,8 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     shared_d_ff: int = 0
-    capacity_factor: float = 1.25
+    moe_aux_coef: float = 0.01       # load-balancing loss coefficient
+    moe_aux: str = "per_layer"       # "per_layer" | "hf" (_stats_summary)
     # ssm
     ssm_state: int = 0
     ssm_head: int = 64
@@ -154,7 +156,6 @@ class ModelConfig:
         return MoEConfig(
             d_model=self.d_model, d_ff=self.moe_d_ff,
             n_experts=self.n_experts, top_k=self.top_k,
-            capacity_factor=self.capacity_factor,
             shared_d_ff=self.shared_d_ff, linear_impl=self.linear_impl,
             spm_stages=self.spm_stages, spm_backward=self.spm_backward,
             spm_use_kernel=self.spm_use_kernel,
@@ -370,7 +371,7 @@ def _apply_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, h: jax.Array,
                  rope: dict, shared_params: Optional[dict],
                  cache: Optional[dict], cache_index, fill_len=None):
     new_cache: dict = {}
-    aux = jnp.zeros((), jnp.float32)
+    stats = None
     if spec.shared_block:
         sc = None if cache is None else cache.get("shared")
         h, nsc = _apply_shared(shared_params, h, cfg, rope, sc, cache_index,
@@ -396,9 +397,52 @@ def _apply_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, h: jax.Array,
     if spec.mlp == "dense":
         h = ffn_block_apply(lp["mlp"], lp["norm2"], h, cfg.ffn_cfg())
     elif spec.mlp == "moe":
-        y, aux = moe_apply(lp["mlp"], rms_norm(lp["norm2"], h), cfg.moe_cfg())
+        y, stats = moe_apply(lp["mlp"], rms_norm(lp["norm2"], h),
+                             cfg.moe_cfg())
         h = h + y
-    return h, (new_cache if cache is not None else None), aux
+    return h, (new_cache if cache is not None else None), stats
+
+
+def _moe_layers(cfg: ModelConfig) -> int:
+    return sum(s.mlp == "moe" for s in cfg.layers)
+
+
+def _stats_init(cfg: ModelConfig) -> dict:
+    """Running sums of the MoE layers' routing statistics (``{}`` for a
+    stack without experts)."""
+    if not _moe_layers(cfg):
+        return {}
+    z = jnp.zeros((), jnp.float32)
+    e = jnp.zeros((cfg.n_experts,), jnp.float32)
+    return {"frac": e, "prob": e, "switch": z, "rows": z, "pad_rows": z,
+            "buffer_rows": z, "load_max": z}
+
+
+def _stats_add(acc: dict, stats: Optional[dict]) -> dict:
+    if stats is None:
+        return acc
+    return {k: (jnp.maximum(acc[k], v) if k == "load_max" else acc[k] + v)
+            for k, v in stats.items()}
+
+
+def _stats_summary(cfg: ModelConfig, acc: dict) -> dict:
+    """The step's auxiliary numbers: ``aux``, the load-balancing loss (0
+    without experts), and the dispatch counters ``moe_rows`` (routed
+    assignments, all layers), ``moe_pad_share`` (tile padding over
+    buffer rows) and ``moe_load_max`` (largest expert's rows over the
+    mean, worst layer).  ``aux`` is, by ``cfg.moe_aux``, HF's
+    ``load_balancing_loss_func`` over every MoE layer's tokens
+    (``"hf"``) or the sum over MoE layers of each layer's loss over
+    ``top_k`` (``"per_layer"``, the Switch form)."""
+    if not acc:
+        return {"aux": jnp.zeros((), jnp.float32)}
+    L = _moe_layers(cfg)
+    aux = (load_balancing_loss(acc["frac"] / L, acc["prob"] / L)
+           if cfg.moe_aux == "hf" else acc["switch"])
+    return {"aux": aux,
+            "moe_rows": acc["rows"],
+            "moe_pad_share": acc["pad_rows"] / acc["buffer_rows"],
+            "moe_load_max": acc["load_max"]}
 
 
 def forward(params: dict, cfg: ModelConfig, *,
@@ -406,7 +450,9 @@ def forward(params: dict, cfg: ModelConfig, *,
             embeds: Optional[jax.Array] = None,
             positions: Optional[jax.Array] = None,
             cache=None, cache_index=None, fill_len=None):
-    """Returns (logits, new_cache, aux_loss).
+    """Returns (logits, new_cache, aux): ``aux`` is a dict of scalars,
+    the load-balancing loss ``aux`` and, for a stack with experts, the
+    dispatch counters (``_stats_summary``).
 
     Three modes:
 
@@ -449,7 +495,7 @@ def forward(params: dict, cfg: ModelConfig, *,
     rope = _rope_tables(cfg, positions)
 
     shared_params = params.get("shared")
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = _stats_init(cfg)
 
     use_scan = cfg.scanned or (cfg.uniform_ignoring_shared
                                and cache is None)
@@ -458,7 +504,7 @@ def forward(params: dict, cfg: ModelConfig, *,
         hybrid = cfg.has_shared_block and not cfg.scanned
 
         def group_body(carry, xs):
-            h, aux = carry
+            h, acc = carry
             if hybrid:
                 if cache is None:
                     gp, flag = xs
@@ -480,13 +526,13 @@ def forward(params: dict, cfg: ModelConfig, *,
                     gp, gc = xs
             new_gc = {}
             for i, spec in enumerate(specs):
-                h, nc, a = _apply_layer(gp[f"l{i}"], spec, cfg, h, rope,
-                                        shared_params, gc[f"l{i}"],
-                                        cache_index, fill_len)
+                h, nc, st = _apply_layer(gp[f"l{i}"], spec, cfg, h, rope,
+                                         shared_params, gc[f"l{i}"],
+                                         cache_index, fill_len)
                 new_gc[f"l{i}"] = nc
-                aux = aux + a
+                acc = _stats_add(acc, st)
             out = None if cache is None else new_gc
-            return (h, aux), out
+            return (h, acc), out
 
         body = group_body
         if cfg.remat and cache is None:
@@ -497,7 +543,7 @@ def forward(params: dict, cfg: ModelConfig, *,
         if hybrid:
             xs.append(jnp.asarray(cfg.shared_flags))
         xs = tuple(xs) if len(xs) > 1 else xs[0]
-        (h, aux_total), new_cache = jax.lax.scan(body, (h, aux_total), xs)
+        (h, stats), new_cache = jax.lax.scan(body, (h, stats), xs)
     else:
         new_cache = [] if cache is not None else None
         stacked = cfg.stacked_params
@@ -511,9 +557,9 @@ def forward(params: dict, cfg: ModelConfig, *,
             if cfg.remat and cache is None:
                 step = jax.checkpoint(_apply_layer,
                                       static_argnums=(1, 2), prevent_cse=False)
-            h, nc, a = step(lp, spec, cfg, h, rope, shared_params, lc,
-                            cache_index, fill_len)
-            aux_total = aux_total + a
+            h, nc, st = step(lp, spec, cfg, h, rope, shared_params, lc,
+                             cache_index, fill_len)
+            stats = _stats_add(stats, st)
             if cache is not None:
                 new_cache.append(nc)
 
@@ -521,7 +567,7 @@ def forward(params: dict, cfg: ModelConfig, *,
         h = rms_norm(params["final_norm"], h)
         logits = unembed(params["embed"], h.astype(cfg.logits_dtype),
                          cfg.embed_cfg())
-    return logits, new_cache, aux_total
+    return logits, new_cache, _stats_summary(cfg, stats)
 
 
 def model_param_count(params) -> int:

@@ -39,7 +39,7 @@ def test_smoke_forward_and_train_step(arch):
     logits, _, aux = T.forward(params, cfg, **kw)
     assert logits.shape == (B, S, cfg.vocab_size)
     assert bool(jnp.all(jnp.isfinite(logits)))
-    assert bool(jnp.isfinite(aux))
+    assert all(bool(jnp.isfinite(v)) for v in aux.values())
 
     step = make_train_step(lambda p, b: LM.lm_loss(p, b, cfg),
                            OptimizerConfig(lr=1e-3, total_steps=10))

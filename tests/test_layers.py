@@ -233,40 +233,17 @@ def test_ssd_chunk_size_invariance():
 # ---------------------------------------------------------------------------
 
 def test_moe_routes_and_balances():
-    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, top_k=2,
-                    group_size=32)
+    from repro.layers.moe import load_balancing_loss
+    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, top_k=2)
     p = init_moe(KEY, cfg)
     x = jax.random.normal(KEY, (2, 32, 16))
-    y, aux = moe_apply(p, x, cfg)
+    y, st = moe_apply(p, x, cfg)
     assert y.shape == x.shape
-    assert bool(jnp.isfinite(aux)) and float(aux) > 0.5   # ~1 when balanced
+    aux = load_balancing_loss(st["frac"], st["prob"])
+    assert bool(jnp.isfinite(aux)) and float(aux) > 1.0   # ~top_k balanced
     g = jax.grad(lambda p: jnp.sum(moe_apply(p, x, cfg)[0] ** 2))(p)
     assert all(bool(jnp.all(jnp.isfinite(t))) for t in jax.tree.leaves(g))
 
-
-def test_moe_capacity_drops_overflow():
-    """With capacity_factor ~0, (almost) all tokens are dropped -> y ~ 0
-    (plus shared expert if any)."""
-    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, top_k=1,
-                    capacity_factor=1e-9, group_size=32)
-    p = init_moe(KEY, cfg)
-    x = jax.random.normal(KEY, (1, 32, 16))
-    y, _ = moe_apply(p, x, cfg)
-    # capacity floor is top_k=1 token per (group, expert): at most 4 of 32
-    # token slots are routed; the rest contribute exactly zero.
-    nonzero_rows = jnp.sum(jnp.any(jnp.abs(y[0]) > 1e-6, axis=-1))
-    assert int(nonzero_rows) <= 4
-
-
-def test_moe_shared_expert_always_on():
-    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, top_k=1,
-                    capacity_factor=1e-9, shared_d_ff=32, group_size=32)
-    p = init_moe(KEY, cfg)
-    x = jax.random.normal(KEY, (1, 32, 16))
-    y, _ = moe_apply(p, x, cfg)
-    # routed path dead, shared path alive => most rows nonzero
-    nonzero_rows = jnp.sum(jnp.any(jnp.abs(y[0]) > 1e-6, axis=-1))
-    assert int(nonzero_rows) >= 28
 
 
 # ---------------------------------------------------------------------------

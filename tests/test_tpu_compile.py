@@ -12,8 +12,9 @@ train batch and for a decode tick, and the RDMA overlap kernel pair on a
 on shows every kernel call, forward, recomputed and backward, under the
 ``spm`` scope (``repro.obs``) and every call of the causal attention
 kernel under ``attn``; that kernel is also compiled alone at a
-qwen3-1.7b train row and a qwen3-32b prefill bucket.  Nothing runs; a passing compile is not
-a chip run.
+qwen3-1.7b train row and a qwen3-32b prefill bucket.  Qwen3-30B-A3B's
+expert maps compile vmapped over dropless row tiles, under ``spm``
+inside ``moe``.  Nothing runs; a passing compile is not a chip run.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and every test worker imports
@@ -281,3 +282,36 @@ def test_train_step_kernels_lie_under_the_spm_scope(one_chip, monkeypatch):
     assert any("splash_mqa_dkv" in n for n in attn)
     assert any("rematted_computation" in n and "splash_mqa_fwd" in n
                for n in attn)
+
+
+def test_dropless_expert_tiles_compile_for_v5e(one_chip, monkeypatch):
+    """Qwen3-30B-A3B's expert maps (2048 <-> 768 over 2048 lanes) run the
+    fused kernel vmapped over 128-row tiles (``layers/moe.py``): forward,
+    backward and the per-tile coefficient gather compile, and every
+    kernel call lies under ``spm`` inside ``moe``."""
+    import re
+
+    from repro import obs
+    from repro.layers.moe import MoEConfig, init_moe, moe_apply
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = MoEConfig(d_model=2048, d_ff=768, n_experts=8, top_k=2,
+                    linear_impl="spm_general", spm_backward="custom")
+    params = jax.eval_shape(lambda: init_moe(jax.random.PRNGKey(0), cfg))
+    on_chip = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), t)
+    x = jax.ShapeDtypeStruct((1, 512, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(moe_apply(p, x, cfg)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).lower(on_chip(params), x).compile() \
+        .as_text()
+    calls = [re.search(r'op_name="([^"]*)"', ln).group(1)
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all(obs.scope_of(n) == "spm"
+                         and "moe" in re.findall(r"[\w.-]+", n)
+                         for n in calls), calls
+    for kernel in ("spm_stack_kernel_call", "spm_stack_bwd_kernel_call"):
+        assert any(f"({kernel})" in n for n in calls), kernel
